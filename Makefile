@@ -1,5 +1,7 @@
 # Developer entry points.  PYTHONPATH=src is the only environment the repo
-# needs; everything runs on a CPU-only host (kernels interpret via Pallas).
+# needs.  Tests and these targets run on the CPU (JAX_PLATFORMS=cpu, Pallas
+# kernels in interpret mode); their timings are CPU wall clocks.  The chip
+# run is `python chip_smoke.py` on a TPU host.
 
 PY ?= python
 export PYTHONPATH := src

@@ -510,16 +510,15 @@ def collect_cache(*, smoke: bool = False) -> dict:
     compile stays quick."""
     import shutil
     import tempfile
-    import jax
     from repro.core.cache import ArtifactCache
     from repro.core.hooks import HOOK_FAULT, HookRegistry
     prog, maps, mats, _vm = _fig1_default_setup(
         max_regions=16 if smoke else 64)
     mat = mats[BATCH_SIZES[0]]
+    # only the unroll pickles go to the throwaway root; jax's compilation
+    # cache stays where enable_xla_cache puts it (a temporary directory
+    # could never be hit again)
     root = tempfile.mkdtemp(prefix="repro-bench-cache-")
-    # enable_xla_cache flips the PROCESS-GLOBAL jax compilation-cache dir;
-    # park it on the bench tmpdir only for the duration of the lane
-    prev_dir = jax.config.jax_compilation_cache_dir
     try:
         laps = []
         for session in ("cold", "warm"):
@@ -536,7 +535,6 @@ def collect_cache(*, smoke: bool = False) -> dict:
                       laps[1]["build_plus_first_batch_s"])
         return {"sessions": laps, "warm_speedup": cold / warm}
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
         shutil.rmtree(root, ignore_errors=True)
 
 

@@ -28,12 +28,11 @@ Run:  PYTHONPATH=src python examples/serve_paged.py [--arch gemma3_27b]
 import argparse
 import json
 
-import jax
 import numpy as np
 
 from repro.configs.base import get_smoke_config
 from repro.core import Profile, ProfileRegion
-from repro.models import PagedLayout, materialize, model_spec
+from repro.models import PagedLayout, serving_params
 from repro.serving import Request, ServingEngine
 
 ap = argparse.ArgumentParser()
@@ -104,7 +103,7 @@ tier_note = (f", {args.tier} over tiers {tier_blocks}" if tier_blocks
              else f", {args.tier} over {args.host_blocks} host blocks"
              if args.host_blocks else "")
 print(f"serving {cfg.name} ({args.policy} policy{tier_note})")
-params = materialize(jax.random.PRNGKey(0), model_spec(cfg))
+params = serving_params(cfg)
 layout = PagedLayout(num_blocks=args.hbm_blocks, block_tokens=4,
                      max_blocks=32)
 

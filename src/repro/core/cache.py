@@ -12,12 +12,16 @@ shapes, so it is cached across sessions under ``.cache/`` (gitignored;
     instruction stream, the map-registry shape contract (slot count +
     capacities), the ctx layout width (``CTX_LEN`` — which is how a tier-
     topology/struct change invalidates entries) and the IR version;
-  * **XLA executables** — jax's persistent compilation cache, pointed at
-    ``.cache/xla``.  Its fingerprint covers the traced computation, which
-    is where the remaining key axes live: the BATCH BUCKET (each padded
-    batch shape is its own entry) and the map capacities.
+  * **XLA executables** — jax's persistent compilation cache.  Its
+    fingerprint covers the traced computation, which is where the remaining
+    key axes live: the BATCH BUCKET (each padded batch shape is its own
+    entry) and the map capacities.  Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set, jax reads it and this module sets no directory; otherwise the
+    cache lives at ``XLA_CACHE_DIR``, a fixed path inside the checkout (the
+    path is part of the cache key, so it must not move between runs).
 
-Environment: ``REPRO_CACHE_DIR`` overrides the root (default ``.cache`` in
+Environment: ``REPRO_CACHE_DIR`` overrides the artifact root (default
+``.cache`` at the root of the checkout, resolved from this file, not from
 the working directory); ``REPRO_CACHE_DIR=0`` (or ``off``) disables disk
 persistence entirely — everything still works, just cold every session.
 ``REPRO_CACHE_MAX_BYTES`` caps the on-disk artifact directory: when a write
@@ -35,6 +39,10 @@ from pathlib import Path
 from .lower import LoweredProgram, unroll_lowered
 
 _DISABLED = ("0", "off", "none", "")
+
+# <checkout>/.cache: src/repro/core/cache.py is four levels below the root
+DEFAULT_ROOT = Path(__file__).resolve().parents[3] / ".cache"
+XLA_CACHE_DIR = DEFAULT_ROOT / "xla"
 
 # Default on-disk artifact budget — far above a normal session's handful of
 # unroll pickles, small enough that a long-lived CI cache can't grow without
@@ -54,7 +62,7 @@ class ArtifactCache:
             if env is not None and env.lower() in _DISABLED:
                 root = None
             else:
-                root = env or ".cache"
+                root = env or DEFAULT_ROOT
         if max_bytes is None:
             max_bytes = int(os.environ.get("REPRO_CACHE_MAX_BYTES",
                                            DEFAULT_MAX_BYTES))
@@ -74,23 +82,21 @@ class ArtifactCache:
 
     # ------------------------------------------------------------- xla cache
     def enable_xla_cache(self) -> None:
-        """Point jax's persistent compilation cache at ``<root>/xla`` so the
-        compiled policy executables (per program x batch bucket) survive the
-        process.  Idempotent; silently a no-op when persistence is disabled
-        or the jax build lacks the knobs."""
+        """Turn on jax's persistent compilation cache so the compiled policy
+        executables (per program x batch bucket) survive the process.  The
+        directory is ``JAX_COMPILATION_CACHE_DIR`` when set (jax reads it
+        itself), else ``XLA_CACHE_DIR``.  Idempotent; a no-op when
+        persistence is disabled."""
         if not self.enabled or self._xla_enabled:
             return
         self._xla_enabled = True
-        try:
-            import jax
-            (self.root / "xla").mkdir(parents=True, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir",
-                              str(self.root / "xla"))
-            # policy programs are tiny and compile fast — cache them anyway
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        except Exception:       # pragma: no cover - older jax knobs
-            pass
+        import jax
+        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+            XLA_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", str(XLA_CACHE_DIR))
+        # policy programs are tiny and compile fast — cache them anyway
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     # ------------------------------------------------------------ lowering
     def unrolled(self, lp: LoweredProgram, injector=None) -> tuple:

@@ -22,6 +22,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable
 
+import jax
 import numpy as np
 
 from ..obs.ringbuf import EV_CACHE, EV_COMPILE, EV_DETACH, EV_HOOK
@@ -64,6 +65,14 @@ PRED_MAX_UNROLL = 512
 # Batches are padded up to power-of-two buckets so XLA compiles one variant
 # per bucket instead of one per distinct batch size.
 PAD_MIN = 4
+
+
+def policy_device() -> jax.Device:
+    """The device every compiled policy batch runs on."""
+    # The host memory manager consumes each decision vector at once: on the
+    # host CPU it never queues behind an in-flight model step on the chip,
+    # and its int64 ALU work is native rather than emulated.
+    return jax.devices("cpu")[0]
 
 
 @dataclass
@@ -282,10 +291,15 @@ class HookRegistry:
         backend = self._batch_backend(ap)
         pad = PAD_MIN
         while True:
-            backend.run_batch(np.zeros((pad, CTX_LEN), dtype=np.int64))
+            self._execute(backend, np.zeros((pad, CTX_LEN), dtype=np.int64))
             if pad >= max_batch:
                 break
             pad *= 2
+
+    @staticmethod
+    def _execute(backend, ctx_mat: np.ndarray) -> np.ndarray:
+        with jax.default_device(policy_device()):
+            return backend.run_batch(ctx_mat)
 
     def run_batch(self, hook: str, ctx_mat: np.ndarray, *,
                   discipline: bool = True) -> np.ndarray | None:
@@ -328,7 +342,7 @@ class HookRegistry:
         timed = tel is not None and tel.enabled
         t0 = time.perf_counter_ns() if timed else 0
         try:
-            out = backend.run_batch(padded)[:n]
+            out = self._execute(backend, padded)[:n]
         except Exception:
             # a crashing batch backend costs one strike and the whole batch
             # falls back to the kernel default — never an engine crash

@@ -206,7 +206,7 @@ class JitPolicy:
     def run(self, ctx_vec: np.ndarray) -> int:
         # enable_x64 scopes true 64-bit ALU semantics to the policy VM without
         # flipping global dtype promotion for the rest of the framework.
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             arrays, lens = self._map_args()
             out = self._single(jnp.asarray(ctx_vec, I64), arrays, lens)
             if self.rb_cap:
@@ -217,7 +217,7 @@ class JitPolicy:
 
     def run_batch(self, ctx_mat: np.ndarray) -> np.ndarray:
         """ctx_mat: [batch, CTX_LEN] -> int64[batch] decisions."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             arrays, lens = self._map_args()
             out = self._batched(jnp.asarray(ctx_mat, I64), arrays, lens)
             if self.rb_cap:

@@ -432,7 +432,7 @@ class PredicatedPolicy:
         return r0
 
     def run_batch(self, ctx_mat: np.ndarray) -> np.ndarray:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             arrays, lens = self._map_args()
             return np.asarray(self._run_segments(
                 jnp.asarray(ctx_mat, I64), arrays, lens))

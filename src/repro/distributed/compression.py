@@ -48,13 +48,8 @@ def compress_residual(x: jax.Array, err: jax.Array):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def compressed_psum_mean(x: jax.Array, mesh: Mesh, axis: str = "data", *,

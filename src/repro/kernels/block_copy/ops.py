@@ -29,7 +29,4 @@ def expand_moves(plan, pad_to: int | None = None):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def apply_moves(pool, src, dst, *, interpret: bool = False):
     """pool: [NB, ...] (any trailing dims); src/dst: [NM]."""
-    shape = pool.shape
-    flat = pool.reshape(shape[0], -1)
-    out = block_copy(flat, src, dst, interpret=interpret)
-    return out.reshape(shape)
+    return block_copy(pool, src, dst, interpret=interpret)

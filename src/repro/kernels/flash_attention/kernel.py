@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -134,7 +132,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             jax.ShapeDtypeStruct((B, H, nq * bq), F32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(qt, kt, vt)

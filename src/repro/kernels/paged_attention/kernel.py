@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -38,11 +36,10 @@ def _kernel(table_ref, logical_ref, len_ref,      # scalar prefetch
             q_ref, k_ref, v_ref,                  # VMEM inputs
             acc_ref, m_ref, l_ref, heat_ref,      # VMEM outputs (revisited)
             *, page_blocks: int, block_tokens: int, kv_heads: int,
-            q_heads: int, head_dim: int, window: int | None):
+            head_dim: int, window: int | None):
     b = pl.program_id(0)
     j = pl.program_id(1)
     pt = page_blocks * block_tokens
-    G = q_heads // kv_heads
     scale = 1.0 / math.sqrt(head_dim)
 
     @pl.when(j == 0)
@@ -55,12 +52,13 @@ def _kernel(table_ref, logical_ref, len_ref,      # scalar prefetch
 
     @pl.when(page_ok)
     def _compute():
-        q = q_ref[0].astype(F32) * scale                     # [H, hd]
-        qg = q.reshape(kv_heads, G, head_dim)
-        k = k_ref[...].astype(F32).reshape(pt, kv_heads, head_dim)
-        v = v_ref[...].astype(F32).reshape(pt, kv_heads, head_dim)
+        # the dots take K/V in the pool's dtype with f32 accumulation: an
+        # f32 copy of a large page's K/V would not fit the scoped VMEM
+        k = k_ref[...].reshape(pt, kv_heads, head_dim)
+        v = v_ref[...].reshape(pt, kv_heads, head_dim)
+        q = (q_ref[0].astype(F32) * scale).astype(k.dtype)   # [KVH, G, hd]
         s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
+            q, k, (((2,), (2,)), ((0,), (1,))),
             preferred_element_type=F32)                      # [KVH, G, pt]
 
         length = len_ref[b]
@@ -71,9 +69,9 @@ def _kernel(table_ref, logical_ref, len_ref,      # scalar prefetch
             valid &= pos > (length - 1 - window)
         s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[0].reshape(kv_heads, G)               # [KVH, G]
-        l_prev = l_ref[0].reshape(kv_heads, G)
-        acc_prev = acc_ref[0].reshape(kv_heads, G, head_dim)
+        m_prev = m_ref[0]                                    # [KVH, G]
+        l_prev = l_ref[0]
+        acc_prev = acc_ref[0]                                # [KVH, G, hd]
 
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -82,18 +80,17 @@ def _kernel(table_ref, logical_ref, len_ref,      # scalar prefetch
                          jnp.exp(m_prev - m_new))
         l_new = l_prev * corr + p.sum(-1)
         pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
+            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
             preferred_element_type=F32)                      # [KVH, G, hd]
-        acc_new = acc_prev * corr[..., None] + pv
 
-        acc_ref[0] = acc_new.reshape(q_heads, head_dim)
-        m_ref[0] = m_new.reshape(q_heads)
-        l_ref[0] = l_new.reshape(q_heads)
-        heat_ref[0, 0] = p.sum()
+        acc_ref[0] = acc_prev * corr[..., None] + pv
+        m_ref[0] = m_new
+        l_ref[0] = l_new
+        heat_ref[...] = jnp.full(heat_ref.shape, p.sum(), F32)
 
     @pl.when(jnp.logical_not(page_ok))
     def _skip():
-        heat_ref[0, 0] = 0.0
+        heat_ref[...] = jnp.zeros(heat_ref.shape, F32)
 
 
 def paged_class_partials(q, pool_k, pool_v, page_table, logical_idx, lengths,
@@ -122,38 +119,46 @@ def paged_class_partials(q, pool_k, pool_v, page_table, logical_idx, lengths,
 
     kern = functools.partial(
         _kernel, page_blocks=page_blocks, block_tokens=block_tokens,
-        kv_heads=KVH, q_heads=H, head_dim=hd, window=window)
+        kv_heads=KVH, head_dim=hd, window=window)
 
     def pool_index(b, j, tbl, logical, lens):
         return (jnp.maximum(tbl[b, j], 0) // page_blocks, 0, 0, 0)
 
+    # Every block's two minor dims equal the array's (the TPU lowering's
+    # rule for blocks not tiled by (8, 128)): the flash state is laid out
+    # [B, KVH, G(, hd)] so that one lane's block is the whole minor slab,
+    # and heat is [B, MP, 1, 1] so that each page owns a (1, 1) block.
+    G = H // KVH
+    state = lambda b, j, *refs: (b, 0, 0, 0)                   # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, MP),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, j, *refs: (b, 0, 0)),
+            pl.BlockSpec((1, KVH, G, hd), state),
             pl.BlockSpec((page_blocks, bt, KVH, hd), pool_index),
             pl.BlockSpec((page_blocks, bt, KVH, hd), pool_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, j, *refs: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, *refs: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, j, *refs: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, *refs: (b, j)),
+            pl.BlockSpec((1, KVH, G, hd), state),
+            pl.BlockSpec((1, KVH, G), lambda b, j, *refs: (b, 0, 0)),
+            pl.BlockSpec((1, KVH, G), lambda b, j, *refs: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda b, j, *refs: (b, j, 0, 0)),
         ],
     )
     out_shapes = [
-        jax.ShapeDtypeStruct((B, H, hd), F32),
-        jax.ShapeDtypeStruct((B, H), F32),
-        jax.ShapeDtypeStruct((B, H), F32),
-        jax.ShapeDtypeStruct((B, MP), F32),
+        jax.ShapeDtypeStruct((B, KVH, G, hd), F32),
+        jax.ShapeDtypeStruct((B, KVH, G), F32),
+        jax.ShapeDtypeStruct((B, KVH, G), F32),
+        jax.ShapeDtypeStruct((B, MP, 1, 1), F32),
     ]
     acc, m, l, heat = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-    )(page_table, logical_idx, lengths, q, pool_k, pool_v)
-    return acc, m, l, heat
+    )(page_table, logical_idx, lengths, q.reshape(B, KVH, G, hd), pool_k,
+      pool_v)
+    return (acc.reshape(B, H, hd), m.reshape(B, H), l.reshape(B, H),
+            heat.reshape(B, MP))

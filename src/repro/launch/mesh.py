@@ -11,16 +11,24 @@ state (the dry-run must set XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    # Callers index and reshape sharded arrays outside shard_map (the
+    # compressed all-reduce's per-shard results) and leave the layout to the
+    # partitioner, which takes Auto axes; jax.make_mesh defaults to Explicit.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
     """Tiny mesh over whatever devices exist (tests / CPU engine)."""
     n = len(jax.devices())
     data = max(1, n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -11,14 +11,12 @@ from __future__ import annotations
 import argparse
 import json
 
-import jax
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config
 from repro.core import Profile, ProfileRegion
-from repro.models.common import materialize
 from repro.models.decode import PagedLayout
-from repro.models.transformer import model_spec
+from repro.models.transformer import serving_params
 from repro.serving import Request, ServingEngine
 
 
@@ -47,7 +45,7 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    params = materialize(jax.random.PRNGKey(0), model_spec(cfg))
+    params = serving_params(cfg)
     max_blocks = -(-(args.prompt_len + args.max_new) // args.block_tokens) + 8
     layout = PagedLayout(num_blocks=args.blocks,
                          block_tokens=args.block_tokens,

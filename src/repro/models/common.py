@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -49,19 +50,31 @@ def _fan_in(shape: tuple[int, ...]) -> int:
     return int(np.prod(shape[:-1])) if len(shape) > 1 else shape[0]
 
 
-def materialize(rng: jax.Array, spec: Pytree) -> Pytree:
+def materialize(rng: jax.Array, spec: Pytree, dtype=None) -> Pytree:
+    """Random parameters for ``spec``; ``dtype`` overrides every leaf's
+    dtype (bf16 serving weights).  Each leaf is drawn by its own compiled
+    init, so building a tree never holds more than one leaf's f32 draw."""
     leaves, treedef = jax.tree.flatten(spec, is_leaf=lambda x: isinstance(x, ParamSpec))
     keys = jax.random.split(rng, len(leaves))
     out = []
     for key, p in zip(keys, leaves):
-        if p.init == "zeros":
-            out.append(jnp.zeros(p.shape, p.dtype))
-        elif p.init == "ones":
-            out.append(jnp.ones(p.shape, p.dtype))
-        else:
-            std = p.scale if p.scale is not None else 1.0 / math.sqrt(max(1, _fan_in(p.shape)))
-            out.append((jax.random.normal(key, p.shape, F32) * std).astype(p.dtype))
+        std = p.scale if p.scale is not None else 1.0 / math.sqrt(max(1, _fan_in(p.shape)))
+        out.append(_init_leaf(key, shape=p.shape, dtype=dtype or p.dtype,
+                              init=p.init, std=std))
     return jax.tree.unflatten(treedef, out)
+
+
+@partial(jax.jit, static_argnames=("shape", "dtype", "init", "std"))
+def _init_leaf(key: jax.Array, *, shape: tuple[int, ...], dtype, init: str,
+               std: float) -> jax.Array:
+    if init == "zeros":
+        return jnp.zeros(shape, dtype)
+    if init == "ones":
+        return jnp.ones(shape, dtype)
+    # the barrier keeps XLA from folding std into the draw's own scaling,
+    # which would move the f32 values by an ulp from the unfused draw
+    draw = jax.lax.optimization_barrier(jax.random.normal(key, shape, F32))
+    return (draw * std).astype(dtype)
 
 
 def abstract(spec: Pytree) -> Pytree:

@@ -221,13 +221,8 @@ def moe_apply_ep(params, x: jax.Array, cfg: MoECfg, mlp_kind: str, mesh,
                         P("model", None))
 
     def _sm(fn, in_specs, out_specs):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except (AttributeError, TypeError):
-            from jax.experimental.shard_map import shard_map
-            return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     tok_spec = P(data_axes) if data_axes else P()
     fn = _sm(

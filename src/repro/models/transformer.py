@@ -22,7 +22,8 @@ from ..configs.base import ModelConfig
 from .attention import (decode_attention_dense, flash_attention,
                         mla_absorbed_decode, mla_expand_attention)
 from .common import (BF16, F32, ParamSpec, activate, apply_mrope, apply_rope,
-                     layer_norm, pad_vocab, rms_norm, sinusoidal_positions)
+                     layer_norm, materialize, pad_vocab, rms_norm,
+                     sinusoidal_positions)
 from .mamba2 import (mamba_apply, mamba_decode_step, mamba_spec,
                      mamba_state_init)
 from .moe import moe_apply, moe_spec
@@ -199,6 +200,13 @@ def model_spec(cfg: ModelConfig) -> Pytree:
             "final_norm": _norm_spec(cfg, d),
         }
     return spec
+
+
+def serving_params(cfg: ModelConfig, seed: int = 0) -> Pytree:
+    """Random weights to serve ``cfg`` with, held in bf16: a float32 tree of
+    Phi-3-mini alone (15.3 GB) would leave no room for a KV pool on a 16 GB
+    chip."""
+    return materialize(jax.random.PRNGKey(seed), model_spec(cfg), dtype=BF16)
 
 
 # ---------------------------------------------------------------------------

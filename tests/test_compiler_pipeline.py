@@ -208,15 +208,6 @@ class TestLDCTXR:
 
 
 class TestArtifactCache:
-    @pytest.fixture(autouse=True)
-    def _restore_xla_cache_dir(self):
-        # enable_xla_cache flips the process-global jax compilation-cache
-        # dir; leave the session the way we found it (tmp_path is deleted)
-        import jax
-        prev = jax.config.jax_compilation_cache_dir
-        yield
-        jax.config.update("jax_compilation_cache_dir", prev)
-
     def _fig1_setup(self):
         maps = MapRegistry()
         m = ArrayMap(512)

@@ -3,6 +3,7 @@
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import jax
@@ -95,7 +96,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.compression import compressed_psum_mean
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh()
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=(8, 4, 32)).astype(np.float32))  # per-shard grads
 mean, err = compressed_psum_mean(g, mesh, "data", mode="int8")
@@ -105,8 +107,9 @@ rel = np.abs(got - exact).max() / (np.abs(exact).max() + 1e-9)
 assert rel < 0.02, rel
 print("REL_ERR", rel)
 """
-        env = dict(os.environ, PYTHONPATH="src")
-        r = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
+        root = Path(__file__).resolve().parents[1]
+        env = dict(os.environ, PYTHONPATH=str(root / "src"))
+        r = subprocess.run([sys.executable, "-c", code], cwd=root,
                            env=env, capture_output=True, text=True,
                            timeout=300)
         assert r.returncode == 0, r.stderr[-2000:]

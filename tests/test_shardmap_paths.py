@@ -11,6 +11,7 @@ Covers the §Perf hillclimb code paths:
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -111,8 +112,9 @@ print("EP MoE OK; aux local/ep:", float(ref_aux), float(aux))
 
 @pytest.mark.timeout(600)
 def test_shardmap_paths_match_references():
-    env = dict(os.environ, PYTHONPATH="src")
-    r = subprocess.run([sys.executable, "-c", CODE], cwd="/root/repo",
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(root / "src"))
+    r = subprocess.run([sys.executable, "-c", CODE], cwd=root,
                        env=env, capture_output=True, text=True, timeout=580)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
     assert "GQA flashdecode OK" in r.stdout
