@@ -38,7 +38,7 @@ bench:
 	$(PY) -m benchmarks.run
 
 # hot-path perf artifact: BENCH_hotpath.json (steps/s, faults/s,
-# policy-invocations/step, mgmt_ns, wall_host_s; scalar vs batched per
+# policy-invocations/step, mgmt_ns, step_wall_s; scalar vs batched per
 # policy and batch size) — the perf trajectory tracked from PR 2 onward
 bench-json:
 	$(PY) -m benchmarks.hotpath_bench --json BENCH_hotpath.json

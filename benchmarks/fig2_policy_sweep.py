@@ -101,7 +101,7 @@ def run_policy(policy: str, *, seed: int = 0, profiles=None) -> dict:
         "blocks_zeroed": mm["blocks_zeroed"],
         "completed": eng.stats.completed,
         "expected": n_req,
-        "host_wall_s": eng.stats.wall_host_s,
+        "step_wall_s": eng.stats.step_wall_s,
     }
 
 

@@ -412,7 +412,7 @@ class _Cell:
             "mgmt_wall_p99_ns": self.mgmt_hist.percentile(99),
             # the cost-model's modeled charge for the window, for reference
             "modeled_mgmt_ns": mid["mgmt_ns"],
-            "wall_host_s": mid["wall"],
+            "step_wall_s": mid["wall"],
         }
 
 

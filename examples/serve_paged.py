@@ -91,7 +91,7 @@ ap.add_argument("--no-containment", action="store_true",
                      "baseline lane")
 ap.add_argument("--trace", default="", metavar="FILE",
                 help="enable telemetry and write a Chrome trace-event JSON "
-                     "(engine spans + mm/program ring events) to FILE")
+                     "(mm/program ring events) to FILE")
 ap.add_argument("--metrics", nargs="?", const="-", default="", metavar="FILE",
                 help="enable telemetry and dump a Prometheus-style metrics "
                      "snapshot to FILE (default: stdout)")
@@ -130,7 +130,7 @@ engine = ServingEngine(cfg, params, layout, max_batch=4, policy=args.policy,
                        profile=profile, host_blocks=args.host_blocks,
                        tier_blocks=tier_blocks, tier_policy=args.tier,
                        batch_faults=not args.scalar_faults,
-                       telemetry=telemetry, trace=bool(args.trace),
+                       telemetry=telemetry,
                        chaos=args.chaos, chaos_rate=args.chaos_rate,
                        containment=not args.no_containment,
                        prefix_cache=args.prefix_cache or False,

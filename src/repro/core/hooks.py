@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from ..obs.ringbuf import EV_CACHE, EV_COMPILE, EV_DETACH, EV_HOOK
+from ..obs.spans import spanned
 from ..resilience.faults import SITE_HOOK_RUN
 from ..resilience import supervisor as _supervisor_mod
 from ..resilience.supervisor import (REASON_INVALID_RETURN,
@@ -301,6 +302,7 @@ class HookRegistry:
         with jax.default_device(policy_device()):
             return backend.run_batch(ctx_mat)
 
+    @spanned("policy/run")
     def run_batch(self, hook: str, ctx_mat: np.ndarray, *,
                   discipline: bool = True) -> np.ndarray | None:
         """Vectorized decision for a batch of faults.

@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..obs.ringbuf import (EV_COLLAPSE, EV_COMPACT, EV_FAULT, EV_RECLAIM)
+from ..obs.spans import spanned
 from ..resilience.supervisor import PolicySupervisor
 from .buddy import RADIX, BuddyAllocator, BuddyError, order_blocks
 from .context import (CTX, CTX_LEN, FIXED_POINT, MAX_TIERS, NUM_ORDERS,
@@ -588,6 +589,7 @@ class MemoryManager:
         return results
 
     # --------------------------------------------------------- batched faults
+    @spanned("mm/fault")
     def fault_batch(self, reqs: list[tuple[int, int, FaultKind]]
                     ) -> list[FaultResult | None]:
         """Resolve many faults through ONE policy invocation.

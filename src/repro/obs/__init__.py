@@ -10,12 +10,14 @@ Three layers, mirroring the kernel-side tooling the paper's ecosystem
   * :mod:`hist` — log2-bucketed histograms (bpftool-profile style) for
     latency/size distributions;
   * :mod:`telemetry` — the per-engine hub tying ring + histograms +
-    counters + trace spans together, with :mod:`trace` (Chrome trace-event
-    JSON, perfetto-loadable) and :mod:`metrics` (flat Prometheus-style
+    counters together, with :mod:`trace` (Chrome trace-event JSON of the
+    ring, perfetto-loadable) and :mod:`metrics` (flat Prometheus-style
     snapshot) as exporters.
 
-This package is numpy-only and imports nothing from :mod:`repro.core`, so
-the core pipeline can depend on it without cycles.
+Host spans go into the JAX profiler's trace through :mod:`spans`, the one
+module here that imports JAX (it is not re-exported).  The package imports
+nothing from :mod:`repro.core`, so the core pipeline can depend on it
+without cycles.
 """
 
 from .hist import Log2Hist

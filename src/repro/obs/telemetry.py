@@ -1,5 +1,6 @@
 """Telemetry hub: one object tying the event ring, the per-hook latency
-histograms, plain counters and (optionally) Chrome-trace spans together.
+histograms and plain counters together.  Host spans are not kept here:
+they go into the JAX profiler's trace (:mod:`repro.obs.spans`).
 
 Cost model: the hot paths guard every tracepoint with a single ``tel is
 None or not tel.enabled`` check, so an engine built without telemetry (the
@@ -12,7 +13,6 @@ at the ``enabled`` flag.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 
 import numpy as np
 
@@ -21,29 +21,19 @@ from .ringbuf import EventRing, tag_name
 
 
 class Telemetry:
-    def __init__(self, *, ring_capacity: int = 8192, trace: bool = False,
+    def __init__(self, *, ring_capacity: int = 8192,
                  enabled: bool = True) -> None:
         self.enabled = bool(enabled)
-        # spans (Chrome-trace timeline) are opt-in on top of metrics: span
-        # bookkeeping appends per step/prefill/decode, which callers only
-        # want when they intend to export a trace.
-        self.trace_enabled = bool(trace) and self.enabled
         self.ring = EventRing(ring_capacity)
         self.hook_invoke_ns: dict[str, Log2Hist] = {}
         self.hook_batch_size: dict[str, Log2Hist] = {}
         self.migrate_path_ns = Log2Hist()   # modeled cost per migration hop
-        self.mgmt_step_ns = Log2Hist()      # wall per management step (bench)
-        # Per-request serving latency: wall ns from submit to the first
-        # sampled token, and wall ns per generated decode token.
-        self.request_ttft_ns = Log2Hist()
-        self.decode_token_ns = Log2Hist()
         self.counters: dict[str, int] = {}
         # drops at the PROGRAM layer: per-lane event slots exhausted inside
         # one invocation (distinct from ring overflow, which is host-side)
         self.prog_lane_drops = 0
         # per-(tier, order) residency in block-ticks, grown on demand
         self._residency = np.zeros((1, 1), np.int64)
-        self.spans: list[tuple] = []        # (name, cat, tid, ts0_ns, dur_ns)
         self._t0 = time.perf_counter_ns()
 
     @classmethod
@@ -81,19 +71,6 @@ class Telemetry:
         if self.enabled:
             self.migrate_path_ns.observe(ns)
 
-    def observe_ttft(self, wall_ns: int) -> None:
-        if self.enabled:
-            self.request_ttft_ns.observe(wall_ns)
-
-    def observe_decode_token(self, wall_ns: int, tokens: int = 1) -> None:
-        """Per-token decode latency: a decode step that produced ``tokens``
-        tokens in ``wall_ns`` contributes one observation per token at the
-        per-token share."""
-        if self.enabled and tokens > 0:
-            per = wall_ns // tokens
-            for _ in range(tokens):
-                self.decode_token_ns.observe(per)
-
     def observe_residency(self, tiers, orders, sizes) -> None:
         """Accumulate per-(tier, order) resident block-ticks — callers pass
         the mapping arrays of one process at one sampling tick."""
@@ -113,19 +90,6 @@ class Telemetry:
                 self._residency
             self._residency = grown
         np.add.at(self._residency, (tiers, orders), sizes)
-
-    @contextmanager
-    def span(self, name: str, cat: str = "engine", tid: str = "engine"):
-        """Chrome-trace complete-event span; a cheap no-op pass-through when
-        span collection is off."""
-        if not self.trace_enabled:
-            yield
-            return
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.spans.append((name, cat, tid, t0, self.now() - t0))
 
     # ----------------------------------------------------------- consumers
     def poll_events(self) -> list[dict]:
@@ -157,9 +121,6 @@ class Telemetry:
             "ring": ring,
             "hooks": hooks,
             "migrate_path_ns": self.migrate_path_ns.snapshot(),
-            "mgmt_step_ns": self.mgmt_step_ns.snapshot(),
-            "request_ttft_ns": self.request_ttft_ns.snapshot(),
-            "decode_token_ns": self.decode_token_ns.snapshot(),
             "counters": dict(self.counters),
             "residency_block_ticks": {
                 f"t{t}_o{o}": int(v)

@@ -1,20 +1,16 @@
 """Chrome trace-event JSON exporter (perfetto / chrome://tracing loadable).
 
-Two process tracks:
-
-  * pid 1 ``host`` — the engine's wall-clock spans (step / prefill /
-    decode) as complete ("X") events, one thread row per span ``tid``;
-  * pid 2 ``modeled`` — the event ring as instant ("i") events.  Program
-    and mm events carry the modeled ktime clock, host-side events a wall
-    timestamp; both are offset-normalized so the track starts near 0.
-    Migration-hop events additionally render as complete ("X") spans on a
-    dedicated ``mm migration`` thread row — each hop carries its modeled
-    transfer duration (``a2`` ns), so a multi-hop demotion reads as a
-    chain of adjacent spans instead of dimensionless ticks.  Profiler
-    events get their own ``mm profiler`` row: EV_WSS samples render as
-    counter ("C") series (the WSS curve per process), program-emitted
-    heat-histogram samples as per-bucket counters, and profile reloads
-    as instants.
+One process track, pid 2 ``modeled``: the event ring as instant ("i")
+events.  Program and mm events carry the modeled ktime clock, host-side
+events a wall timestamp; both are offset-normalized so the track starts
+near 0.  Migration-hop events additionally render as complete ("X") spans
+on a dedicated ``mm migration`` thread row — each hop carries its modeled
+transfer duration (``a2`` ns), so a multi-hop demotion reads as a chain of
+adjacent spans instead of dimensionless ticks.  Profiler events get their
+own ``mm profiler`` row: EV_WSS samples render as counter ("C") series (the
+WSS curve per process), program-emitted heat-histogram samples as
+per-bucket counters, and profile reloads as instants.  Host spans of the
+engine are in the JAX profiler's trace instead (:mod:`repro.obs.spans`).
 
 Timestamps are microseconds (the trace-event format's unit); sub-``us``
 durations survive as fractions.
@@ -29,24 +25,8 @@ from .ringbuf import (EV_MIGRATE_HOP, EV_PROFILE, EV_WSS, PROF_TAG_HEAT,
 
 
 def chrome_trace(tel) -> dict:
-    events = []
-    tids: dict[str, int] = {}
-
-    def tid_of(name: str) -> int:
-        if name not in tids:
-            tids[name] = len(tids) + 1
-            events.append({"ph": "M", "name": "thread_name", "pid": 1,
-                           "tid": tids[name], "args": {"name": name}})
-        return tids[name]
-
-    events.append({"ph": "M", "name": "process_name", "pid": 1,
-                   "args": {"name": "host"}})
-    events.append({"ph": "M", "name": "process_name", "pid": 2,
-                   "args": {"name": "modeled"}})
-    for name, cat, tid, ts0, dur in tel.spans:
-        events.append({"ph": "X", "name": name, "cat": cat, "pid": 1,
-                       "tid": tid_of(tid), "ts": ts0 / 1000.0,
-                       "dur": dur / 1000.0})
+    events = [{"ph": "M", "name": "process_name", "pid": 2,
+               "args": {"name": "modeled"}}]
     ring = tel.ring.peek()
     base = int(ring[:, 0].min()) if len(ring) else 0
     have_hops = False

@@ -39,7 +39,7 @@ capacity-sweep benchmark sweeps these.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -50,6 +50,7 @@ import numpy as np
 from ..obs import (Telemetry, flatten_metrics, render_prometheus,
                    write_chrome_trace)
 from ..obs.ringbuf import EV_PREEMPT
+from ..obs.spans import span, spanned
 
 from ..configs.base import ModelConfig
 from ..core import (MAX_PROFILE_REGIONS, FaultKind, HWSpec, Khugepaged,
@@ -69,10 +70,14 @@ from ..models.decode import (PagedLayout, cache_init, decode_step,
                              prefill_step, prefill_suffix_step)
 from ..models.transformer import build_layer_plans
 from .prefix_cache import PrefixCache
+from .program import DeviceProgram
 from .sampler import Sampler
 from .tables import DeviceBlockTables
 
 Pytree = Any
+
+# finished requests whose times the engine keeps (the newest)
+REQUEST_LOG = 4096
 
 
 @dataclass
@@ -96,6 +101,17 @@ class SeqState:
 
 
 @dataclass
+class RequestTimes:
+    """One request's way through the engine, on ``time.perf_counter``:
+    handed to ``submit``, admitted into a slot (first admission, before its
+    prefill) and its first token sampled."""
+    rid: int
+    submitted: float
+    admitted: float | None = None
+    first_token: float | None = None
+
+
+@dataclass
 class EngineStats:
     steps: int = 0
     prefills: int = 0
@@ -103,11 +119,22 @@ class EngineStats:
     decode_tokens: int = 0
     preemptions: int = 0
     tier_reliefs: int = 0          # OOMs resolved by demotion, not preemption
-    wall_host_s: float = 0.0
+    step_wall_s: float = 0.0       # host wall time inside step(), summed
     completed: int = 0
+    # first calls of a device-program variant (compiled or loaded from the
+    # persistent cache), in all and per entry
+    compiles: int = 0
+    compiles_by_entry: dict = field(default_factory=dict)
+
+    def count_compile(self, entry: str) -> None:
+        self.compiles += 1
+        self.compiles_by_entry[entry] = \
+            self.compiles_by_entry.get(entry, 0) + 1
 
     def snapshot(self) -> dict:
-        return dict(self.__dict__)
+        out = dict(self.__dict__)
+        out["compiles_by_entry"] = dict(self.compiles_by_entry)
+        return out
 
 
 class ServingEngine:
@@ -146,18 +173,15 @@ class ServingEngine:
                  evict_policy: str = "lru-evict",
                  batch_faults: bool = True,
                  telemetry: "Telemetry | bool | None" = None,
-                 trace: bool = False,
                  chaos: "int | FailureInjector | None" = None,
                  chaos_rate: float = 0.02,
                  containment: bool = True):
         # telemetry: None (default — zero-overhead no-op), True (counters/
-        # histograms/ring), or a repro.obs.Telemetry instance.  trace=True
-        # additionally records engine spans for the Chrome-trace exporter
-        # (and implies telemetry when none was passed).
-        if telemetry is True or (telemetry is None and trace):
-            telemetry = Telemetry(trace=trace)
-        elif telemetry is not None and trace:
-            telemetry.trace_enabled = telemetry.enabled
+        # histograms/ring), or a repro.obs.Telemetry instance.  Host spans
+        # (repro.obs.spans) need no option: they record into a running JAX
+        # profiler session.
+        if telemetry is True:
+            telemetry = Telemetry()
         self.telemetry: Telemetry | None = telemetry or None
         # chaos: None (default — no injection, zero overhead), an int seed
         # (uniform chaos_rate at every failure site), or a pre-configured
@@ -334,9 +358,9 @@ class ServingEngine:
         self.active: dict[int, SeqState] = {}    # slot -> seq
         self._next_pid = 1
         self.finished: dict[int, list[int]] = {}
-        # rid -> [trace-clock t0, wall t0 or None once TTFT was observed]:
-        # per-request serving-latency bookkeeping (telemetry only)
-        self._req_t0: dict[int, list] = {}
+        # request times: live requests by rid, then the newest finished ones
+        self._times: dict[int, RequestTimes] = {}
+        self.request_log: deque[RequestTimes] = deque(maxlen=REQUEST_LOG)
         # per-app aggregate per-logical-block heat — the DAMON trace used by
         # offline profiling (profile_from_heat)
         self.heat_histograms: dict[str, np.ndarray] = {}
@@ -402,24 +426,22 @@ class ServingEngine:
                 return leaf.at[d].set(leaf[src], mode="drop")
             return jax.tree_util.tree_map_with_path(move, cache)
 
-        self._decode = jax.jit(_decode_entry)
-        self._prefill = jax.jit(_prefill_entry)
-        self._prefill_sfx = jax.jit(_prefill_sfx_entry,
+        st = self.stats
+        self._decode = DeviceProgram(_decode_entry, st)
+        self._prefill = DeviceProgram(_prefill_entry, st)
+        self._prefill_sfx = DeviceProgram(_prefill_sfx_entry, st,
                                     static_argnames=("key_blocks",))
-        self._moves = jax.jit(_moves_entry)
+        self._moves = DeviceProgram(_moves_entry, st)
 
     # ----------------------------------------------------------------- admin
-    def _span(self, name: str, tid: str = "engine"):
-        tel = self.telemetry
-        if tel is None or not tel.trace_enabled:
-            return nullcontext()
-        return tel.span(name, cat="engine", tid=tid)
-
     def submit(self, req: Request) -> None:
-        tel = self.telemetry
-        if tel is not None and tel.enabled and req.rid not in self._req_t0:
-            self._req_t0[req.rid] = [tel.now(), time.perf_counter_ns()]
+        if req.rid not in self._times:
+            self._times[req.rid] = RequestTimes(req.rid, time.perf_counter())
         self.waiting.append(req)
+
+    def request_times(self) -> list[RequestTimes]:
+        """Times of the newest finished requests, then of the live ones."""
+        return list(self.request_log) + list(self._times.values())
 
     def _free_slots(self) -> list[int]:
         return [s for s in range(self.max_batch) if s not in self.active]
@@ -428,6 +450,7 @@ class ServingEngine:
         return -(-tokens // self.layout.block_tokens)
 
     # --------------------------------------------------------------- prefill
+    @spanned("serve/admit")
     def _admit(self) -> None:
         bt = self.layout.block_tokens
         for slot in self._free_slots():
@@ -450,12 +473,13 @@ class ServingEngine:
             # prefix-cache admission: borrow the longest cached prefix
             # read-only (page-table surgery, no kernel work), fault only the
             # uncached suffix blocks, CoW-break a partially shared tail
-            match = (self.prefix_cache.acquire(pid, req.prompt)
-                     if self.prefix_cache is not None else None)
-            n_shared = 0
-            if match is not None:
-                self.mm.map_shared(pid, 0, match.blocks)
-                n_shared = len(match.blocks)
+            match, n_shared = None, 0
+            if self.prefix_cache is not None:
+                with span("serve/prefix"):
+                    match = self.prefix_cache.acquire(pid, req.prompt)
+                    if match is not None:
+                        self.mm.map_shared(pid, 0, match.blocks)
+                        n_shared = len(match.blocks)
             if self.batch_faults:
                 # the whole prefill span resolves through ONE policy
                 # invocation (bulk FaultKind.PREFILL placement hints)
@@ -484,19 +508,19 @@ class ServingEngine:
             seq = SeqState(req=req, pid=pid, slot=slot,
                            length=len(req.prompt), prefix=match)
             self.active[slot] = seq
-            with self._span(f"prefill rid={req.rid}"):
-                self._run_prefill(seq)
-            tel = self.telemetry
-            rec = self._req_t0.get(req.rid)
-            if tel is not None and rec is not None and rec[1] is not None:
+            times = self._times.get(req.rid)
+            if times is not None and times.admitted is None:
+                times.admitted = time.perf_counter()
+            self._run_prefill(seq)
+            if times is not None and times.first_token is None:
                 # the prefill above sampled the request's first token
-                tel.observe_ttft(time.perf_counter_ns() - rec[1])
-                rec[1] = None
+                times.first_token = time.perf_counter()
             if self.prefix_cache is not None:
                 # cache every whole block of the freshly prefilled prompt
                 # (existing chain entries are skipped; copies ride the next
                 # move-list drain, before any kernel can touch the donor)
-                self.prefix_cache.insert(pid, req.prompt)
+                with span("serve/prefix"):
+                    self.prefix_cache.insert(pid, req.prompt)
             self.stats.prefills += 1
 
     def _slot_pids(self) -> list:
@@ -506,6 +530,7 @@ class ServingEngine:
             sp[slot] = seq.pid
         return sp
 
+    @spanned("serve/tables")
     def _sync_tables(self, slot_pids) -> tuple:
         """Dirty-row sync of the device-resident block tables.
 
@@ -531,6 +556,7 @@ class ServingEngine:
             tri = np.concatenate([tri, pad])
         return jnp.asarray(idx), jnp.asarray(rows), active, jnp.asarray(tri)
 
+    @spanned("serve/prefill")
     def _run_prefill(self, seq: SeqState) -> None:
         bt = self.layout.block_tokens
         prompt = np.asarray(seq.req.prompt, np.int32)
@@ -545,7 +571,6 @@ class ServingEngine:
         # installs it and slices the slot's row from the persistent buffer
         didx, drows, _active, tri = self._sync_tables(self._slot_pids())
         kw = self._modality_kwargs(1, S_pad)
-        sub_cache = jax.tree.map(lambda c: c, self.cache)  # pools are shared
         logits, new_cache, self._table_buf = self._prefill(
             self.params, self._slot_cache_view(seq.slot), self._table_buf,
             didx, drows, tri, jnp.asarray(toks),
@@ -556,9 +581,15 @@ class ServingEngine:
         self.mm.record_access(seq.pid,
                               np.ones(self._blocks_needed(len(prompt))))
         self.stats.prefill_tokens += len(prompt)
-        tok = self.sampler.sample(np.asarray(logits)[0],
-                                  self.cfg.vocab, seq.req.temperature)
-        seq.generated.append(int(tok))
+        self._sample_first(seq, logits)
+
+    def _sample_first(self, seq: SeqState, logits) -> None:
+        """The prefill's token: wait for its logits, then sample."""
+        with span("serve/sync"):
+            row = np.asarray(logits)[0]
+        with span("serve/sample"):
+            seq.generated.append(int(self.sampler.sample(
+                row, self.cfg.vocab, seq.req.temperature)))
 
     def _run_prefill_suffix(self, seq: SeqState, match) -> None:
         """Cache-hit prefill: the first ``match.tokens`` tokens' KV is
@@ -588,9 +619,7 @@ class ServingEngine:
         self._merge_slot_cache(seq.slot, new_cache)
         self.mm.record_access(seq.pid, np.ones(KB))
         self.stats.prefill_tokens += L - S0
-        tok = self.sampler.sample(np.asarray(logits)[0],
-                                  self.cfg.vocab, seq.req.temperature)
-        seq.generated.append(int(tok))
+        self._sample_first(seq, logits)
 
     # -------------------------------------------------- per-slot cache views
     # Pools (block dim) are global — shared across slots.  Per-sequence state
@@ -661,6 +690,7 @@ class ServingEngine:
         return kw
 
     # ---------------------------------------------------------------- reclaim
+    @spanned("serve/fault")
     def _ensure_with_reclaim(self, fault_fn, faulting_pid: int,
                              need_blocks: int, *,
                              allow_preempt: bool = True) -> bool:
@@ -719,48 +749,38 @@ class ServingEngine:
                 return
         self.mm.evict_process(victim_pid)
 
+    @spanned("serve/step")
     def step(self) -> bool:
         """One engine iteration. Returns False when all work is done."""
-        t0 = time.monotonic()
-        tel = self.telemetry
-        with self._span(f"step {self.stats.steps}"):
-            self._admit()
-            if not self.active and not self.waiting:
-                return False
-            if self.active:
-                tok0 = self.stats.decode_tokens
-                d0 = time.perf_counter_ns()
-                with self._span("decode"):
-                    self._decode_once()
-                if tel is not None and tel.enabled:
-                    tel.observe_decode_token(
-                        time.perf_counter_ns() - d0,
-                        self.stats.decode_tokens - tok0)
-            with self._span("mm-tick", tid="mm"):
-                if self.khugepaged is not None:
-                    self.khugepaged.tick()
-                if isinstance(self.mm, TieredMemoryManager):
-                    # background promotion: bring re-heated host-tier pages
-                    # back to HBM
-                    self.mm.promotion_scan()
-                if self.prefix_cache is not None:
-                    # periodic eviction cadence (batched HOOK_EVICT scan)
-                    self.prefix_cache.tick()
-                self._apply_pending_moves()
-                self.mm.tick()
-                if self.profiler is not None and self.active:
-                    # sampled HOOK_PROFILE scan + profile synthesis/reload
-                    self.profiler.tick(
-                        [(seq.pid, self.mm.procs[seq.pid].app)
-                         for seq in self.active.values()
-                         if seq.pid in self.mm.procs])
+        t0 = time.perf_counter()
+        self._admit()
+        if not self.active and not self.waiting:
+            return False
+        if self.active:
+            self._decode_once()
+        with span("serve/tick"):
+            if self.khugepaged is not None:
+                self.khugepaged.tick()
+            if isinstance(self.mm, TieredMemoryManager):
+                # background promotion: bring re-heated host-tier pages
+                # back to HBM
+                self.mm.promotion_scan()
+            if self.prefix_cache is not None:
+                # periodic eviction cadence (batched HOOK_EVICT scan)
+                self.prefix_cache.tick()
+            self._apply_pending_moves()
+            self.mm.tick()
+            if self.profiler is not None and self.active:
+                # sampled HOOK_PROFILE scan + profile synthesis/reload
+                self.profiler.tick(
+                    [(seq.pid, self.mm.procs[seq.pid].app)
+                     for seq in self.active.values()
+                     if seq.pid in self.mm.procs])
         self.stats.steps += 1
-        dt = time.monotonic() - t0
-        self.stats.wall_host_s += dt
-        if tel is not None and tel.enabled:
-            tel.mgmt_step_ns.observe(int(dt * 1e9))
+        self.stats.step_wall_s += time.perf_counter() - t0
         return bool(self.active or self.waiting)
 
+    @spanned("serve/fault")
     def _fault_slots_batched(self) -> set[int]:
         """Resolve every active slot's potential boundary crossing through a
         single ``fault_batch`` — with a fault program attached, a full decode
@@ -798,6 +818,7 @@ class ServingEngine:
         return {slot for slot, seq in self.active.items()
                 if (seq.length // bt) in self.mm.procs[seq.pid].mapped}
 
+    @spanned("serve/fault")
     def _fault_slots_scalar(self) -> set[int]:
         """Pre-batching fault path: one ``ensure_mapped`` (one ctx build, one
         policy invocation) per faulting slot.  Kept for the hot-path
@@ -832,6 +853,7 @@ class ServingEngine:
                   FaultKind.FIRST_TOUCH) for s in sorted(ok)])
         return ok
 
+    @spanned("serve/decode")
     def _decode_once(self) -> None:
         B, MB = self.max_batch, self.layout.max_blocks
         tokens = np.zeros(B, np.int32)
@@ -874,8 +896,16 @@ class ServingEngine:
             self.params, self.cache, self._table_buf, didx, drows, tri,
             jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(active), pos3d)
-        logits_np = np.asarray(logits)
-        heat_np = np.asarray(heat)
+        with span("serve/sync"):
+            logits_np = np.asarray(logits)
+            heat_np = np.asarray(heat)
+        self._fold_and_sample(skipped, logits_np, heat_np)
+
+    @spanned("serve/sample")
+    def _fold_and_sample(self, skipped, logits_np, heat_np) -> None:
+        """A decode step's host tail: fold each row's attention mass into
+        DAMON (and the prefix cache's heat), sample its token and retire
+        the rows that reached their limit."""
         for slot, seq in list(self.active.items()):
             if slot in skipped:
                 # its batch row decoded with no block table — the logits are
@@ -908,15 +938,11 @@ class ServingEngine:
                 self.mm.free_process(seq.pid)
                 del self.active[slot]
                 self.stats.completed += 1
-                tel = self.telemetry
-                rec = self._req_t0.pop(seq.req.rid, None)
-                if rec is not None and tel is not None and tel.trace_enabled:
-                    # whole-request span (submit -> last token) on its own
-                    # trace row
-                    tel.spans.append((f"req {seq.req.rid}", "request",
-                                      "requests", rec[0],
-                                      tel.now() - rec[0]))
+                times = self._times.pop(seq.req.rid, None)
+                if times is not None:
+                    self.request_log.append(times)
 
+    @spanned("serve/moves")
     def _apply_pending_moves(self) -> None:
         moves = self.mm.drain_moves()
         if not moves:
@@ -987,11 +1013,10 @@ class ServingEngine:
     # ------------------------------------------------------------ telemetry
     def write_trace(self, path) -> None:
         """Write the Chrome trace-event JSON (load in Perfetto / chrome://
-        tracing): engine spans on the wall-clock track, mm/program ring
-        events on the modeled-clock track."""
+        tracing) of the mm/program ring events, on the modeled clock."""
         if self.telemetry is None:
             raise ValueError("engine was built without telemetry "
-                             "(pass trace=True or telemetry=...)")
+                             "(pass telemetry=...)")
         write_chrome_trace(self.telemetry, path)
 
     def write_wss_curve(self, path) -> None:
@@ -1004,10 +1029,12 @@ class ServingEngine:
 
     def metrics(self) -> dict:
         """Flat ``{metric_name: number}`` snapshot across every subsystem:
-        engine stats, mm stats, hook counters, artifact-cache stats, tier
-        pools, and (when telemetry is on) histograms/counters/ring stats."""
+        engine stats, request latencies, mm stats, hook counters,
+        artifact-cache stats, tier pools, and (when telemetry is on)
+        histograms/counters/ring stats."""
         sections = {
             "engine": self.stats.snapshot(),
+            "requests": self._request_percentiles(),
             "mm": self.mm.stats.snapshot(),
             "huge_fraction": self.mm.hugepage_block_fraction(),
             "hooks": {"invocations": self.mm.hooks.invocations,
@@ -1029,6 +1056,21 @@ class ServingEngine:
         if self.telemetry is not None and self.telemetry.enabled:
             sections["telemetry"] = self.telemetry.snapshot()
         return flatten_metrics(sections)
+
+    def _request_percentiles(self) -> dict:
+        """Admit wait (submit -> admission) and time to first token (submit
+        -> first token) in ms, p50 and p99, over the requests the engine
+        has times of."""
+        times = self.request_times()
+        out: dict = {"count": len(times)}
+        for name, attr in (("admit_wait", "admitted"),
+                           ("ttft", "first_token")):
+            v = [1e3 * (getattr(t, attr) - t.submitted) for t in times
+                 if getattr(t, attr) is not None]
+            if v:
+                out[f"{name}_ms_p50"] = float(np.percentile(v, 50))
+                out[f"{name}_ms_p99"] = float(np.percentile(v, 99))
+        return out
 
     def metrics_text(self) -> str:
         """Prometheus-style text exposition of :meth:`metrics`."""

@@ -27,9 +27,10 @@ from repro.core.hooks import HOOK_FAULT, HookRegistry
 from repro.core.lower import RB_MAX_PER_RUN, lower
 from repro.core.predicate import PredicatedPolicy
 from repro.core.vm import HELPER_RINGBUF_OUTPUT, HELPER_TRACE
-from repro.obs import (EV_FAULT, EV_HOOK, EV_PROG_BASE, EV_PROG_TRACE,
-                       EventRing, Log2Hist, Telemetry, chrome_trace,
-                       flatten_metrics, render_prometheus, tag_name)
+from repro.obs import (EV_FAULT, EV_HOOK, EV_MIGRATE_HOP, EV_PROG_BASE,
+                       EV_PROG_TRACE, EventRing, Log2Hist, Telemetry,
+                       chrome_trace, flatten_metrics, render_prometheus,
+                       tag_name)
 
 
 # ------------------------------------------------------------ ring buffer
@@ -288,16 +289,16 @@ class TestWorkloadEventParity:
 
 # -------------------------------------------------------- exporter schema
 def _populated_telemetry() -> Telemetry:
-    tel = Telemetry(trace=True)
+    tel = Telemetry()
     tel.emit(EV_FAULT, 1, 5, 2, ts=1_000)
     tel.emit(EV_PROG_BASE, 5, 1, 3, ts=2_000)
+    # a demotion hop (tier 0 -> 1, 4096 bytes, 30 us modeled)
+    tel.emit(EV_MIGRATE_HOP, 1, 4096, 30_000, ts=3_000)
     tel.observe_hook("mm_fault", 12_000, 4)
     tel.observe_migrate(30_000)
     tel.inc("backend_builds")
     tel.observe_residency(np.array([0, 1]), np.array([1, 0]),
                           np.array([4, 1]))
-    with tel.span("step 0"):
-        pass
     return tel
 
 
@@ -305,9 +306,7 @@ class TestTelemetrySchema:
     def test_snapshot_schema_stable(self):
         snap = _populated_telemetry().snapshot()
         assert set(snap) == {"enabled", "ring", "hooks", "migrate_path_ns",
-                             "mgmt_step_ns", "request_ttft_ns",
-                             "decode_token_ns", "counters",
-                             "residency_block_ticks"}
+                             "counters", "residency_block_ticks"}
         assert set(snap["ring"]) == {"capacity", "pending", "emitted",
                                      "dropped", "prog_lane_drops"}
         hook = snap["hooks"]["mm_fault"]
@@ -329,11 +328,16 @@ class TestTelemetrySchema:
         assert set(doc) == {"traceEvents", "displayTimeUnit"}
         events = doc["traceEvents"]
         phases = {e["ph"] for e in events}
-        assert "X" in phases            # spans
+        assert "X" in phases            # migration-hop spans
         assert "i" in phases            # ring instants
         assert "M" in phases            # process/thread metadata
         for e in events:
             assert {"ph", "pid", "name"} <= set(e)
+        # the ring's modeled-clock track only: host spans live in the JAX
+        # profiler's trace
+        assert {e["pid"] for e in events} == {2}
+        hop = [e for e in events if e["ph"] == "X"]
+        assert hop[0]["name"] == "hop t0->t1" and hop[0]["dur"] == 30.0
         # round-trips through JSON (perfetto-loadable)
         path = tmp_path / "trace.json"
         from repro.obs import write_chrome_trace

@@ -252,7 +252,7 @@ class TestProfilerEventSchema:
         assert tag_name(PROF_TAG_BENEFIT) == "prof_benefit"
 
     def test_trace_grows_profiler_track(self):
-        tel = Telemetry(trace=True)
+        tel = Telemetry()
         tel.emit(EV_WSS, 1, 12, 20, ts=1_000)
         tel.emit(PROF_TAG_HEAT, 1, 5, 8, ts=1_500)
         tel.emit(EV_PROFILE, 1, 2, 3, ts=2_000)

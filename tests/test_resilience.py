@@ -390,7 +390,7 @@ class TestEngineResilience:
     def test_detach_visible_in_metrics_and_trace(self, engine_setup, tmp_path):
         inj = FailureInjector(3, {SITE_HOOK_RUN: 1.0})
         eng = run_engine(engine_setup, policy="ebpf", chaos=inj,
-                         telemetry=True, trace=True)
+                         telemetry=True)
         assert eng.stats.completed == 4          # fallback kept serving
         assert not eng.mm.hooks.attached(HOOK_FAULT)
         m = eng.metrics()
