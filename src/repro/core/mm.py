@@ -1000,7 +1000,9 @@ class MemoryManager:
         return self.procs[pid].table_version
 
     def page_lists_by_order(self, pids: list[int]) -> dict[int, np.ndarray]:
-        """Per-order page lists for the multi-size paged-attention kernel.
+        """Per-order page lists: the pages as the manager mapped them.  The
+        paged decode kernel finds the same pages (or larger ones) in the
+        block table on the device (``kernels.paged_attention.ops``).
 
         Returns {order: int32[[seq_slot, logical_page_idx, phys_page_start]]}.
         seq_slot is the position of the pid in ``pids``.  Assembled from the

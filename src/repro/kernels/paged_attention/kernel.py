@@ -1,20 +1,25 @@
-"""Pallas TPU kernel: multi-size paged flash-decoding, one size class per call.
+"""Pallas TPU kernel: decode attention that reads only a batch's live KV pages.
 
-Grid: (B, MP) — one program per (sequence, class-page).  The page table and
-logical indices ride in scalar-prefetch memory so the BlockSpec index map can
-steer the K/V DMA straight at the page's pool rows: a class-c page is
-4^c buddy-ALIGNED consecutive pool blocks, so its whole K/V arrives in ONE
-contiguous VMEM copy of (4^c * block_tokens) tokens.  This is the TPU-native
-payoff of the paper's huge pages: one descriptor + one large contiguous DMA
-per page instead of 4^c small ones (cf. TLB reach), and per-page transfer
-size is what drives effective HBM bandwidth.
+One call serves every row of the batch for one layer.  The caller
+(``ops.page_work``) lists, row by row, the pages that hold the rows' valid
+tokens.  A page of order c is an aligned run of 4^c logical blocks whose
+physical blocks are consecutive and start on a multiple of 4^c (the buddy
+allocator's page), so its K and V each arrive in ONE contiguous DMA; a
+64-block page streams as four contiguous 16-block slices, which bounds the
+VMEM a block buffer takes.  The grid has one step per item of the list (its
+length is a scalar the call reads), so unmapped blocks, blocks past a row's
+length and inactive rows cost nothing, and the kernel's cost grows with the
+pages it reads, not with ``max_blocks``.  Each item size (1, 4, 16 blocks)
+has its own BlockSpec, steered by the scalar-prefetched list; the pipeline
+copies the next item's K and V while this one computes.
 
-Flash state (m, l, acc) lives in revisited output blocks (index maps constant
-in j, the innermost grid dim), initialized at j == 0 — the standard Pallas
-reduction pattern.  The kernel also emits per-page attention mass ("heat"),
-the DAMON signal; heat is normalized against the RUNNING max at visit time
-(exact mass needs a second pass; DAMON only consumes relative heat — see
-ref.paged_class_heat_running_ref which mirrors this semantics exactly).
+Per row the flash state (m, l, acc) lives in VMEM scratch.  K and V come in
+the pool's dtype and are computed with in f32 on the VPU, in the layout the
+pool holds them, and the softmax is exact.  The heat (softmax mass per
+logical block, summed over heads: the DAMON signal) is kept per (head,
+block) in the frame of the row's running max, rescaled with ``acc``, and
+divided by the final sum at the end, so it equals the mass a full softmax
+gives.
 """
 
 from __future__ import annotations
@@ -27,138 +32,147 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
 F32 = jnp.float32
 NEG_INF = -1e30
+SLICE_KINDS = 3              # a work item moves 1, 4 or 16 blocks
 
 
-def _kernel(table_ref, logical_ref, len_ref,      # scalar prefetch
-            q_ref, k_ref, v_ref,                  # VMEM inputs
-            acc_ref, m_ref, l_ref, heat_ref,      # VMEM outputs (revisited)
-            *, page_blocks: int, block_tokens: int, kv_heads: int,
-            head_dim: int, window: int | None):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    pt = page_blocks * block_tokens
-    scale = 1.0 / math.sqrt(head_dim)
+def _kernel(row_ref, lblk_ref, kind_ref, n_ref, len_ref, *refs,
+            block_tokens: int, window: int | None):
+    q_ref, *kv_refs, o_ref, heat_ref, m_sc, l_sc, acc_sc, hp_sc = \
+        refs[SLICE_KINDS:]        # the first refs feed the index maps alone
+    bt = block_tokens
+    G, KVH, hd = q_ref.shape[1:]
+    MBp = hp_sc.shape[-1]
+    scale = 1.0 / math.sqrt(hd)
+    i = pl.program_id(0)
 
-    @pl.when(j == 0)
+    @pl.when(i == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        hp_sc[...] = jnp.zeros_like(hp_sc)
 
-    page_ok = table_ref[b, j] >= 0
-
-    @pl.when(page_ok)
-    def _compute():
-        # the dots take K/V in the pool's dtype with f32 accumulation: an
-        # f32 copy of a large page's K/V would not fit the scoped VMEM
-        k = k_ref[...].reshape(pt, kv_heads, head_dim)
-        v = v_ref[...].reshape(pt, kv_heads, head_dim)
-        q = (q_ref[0].astype(F32) * scale).astype(k.dtype)   # [KVH, G, hd]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=F32)                      # [KVH, G, pt]
-
-        length = len_ref[b]
-        pos = logical_ref[b, j] * pt + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, pt), 2)
+    def block(k_ref, v_ref, r, first, j):
+        # one block of the item: [bt, KVH, hd] as the pool holds it.  A
+        # score is a product summed over the lanes, and P.V a
+        # lane-broadcast weight summed over the tokens, so neither needs a
+        # transpose
+        k = k_ref[j].astype(F32)
+        v = v_ref[j].astype(F32)
+        length = len_ref[r]
+        pos = (first + j) * bt + jax.lax.broadcasted_iota(
+            jnp.int32, (bt, 1, 1), 0)
         valid = pos < length
         if window is not None:
             valid &= pos > (length - 1 - window)
-        s = jnp.where(valid, s, NEG_INF)
+        here = jax.lax.broadcasted_iota(jnp.int32, (1, MBp), 1) == first + j
+        for g in range(G):
+            q = q_ref[r, g].astype(F32)                       # [KVH, hd]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            s = jnp.where(valid, s, NEG_INF)                  # [bt, KVH, 1]
+            m_prev = m_sc[r, g]                               # [KVH, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.where(valid, jnp.exp(s - m_new[None]), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            mass = jnp.sum(p, axis=0)                         # [KVH, 1]
+            m_sc[r, g] = m_new
+            l_sc[r, g] = l_sc[r, g] * corr + mass
+            acc_sc[r, g] = acc_sc[r, g] * corr + jnp.sum(p * v, axis=0)
+            # the block's mass per head, in its column of the row's heat
+            hp_sc[r, g] = hp_sc[r, g] * corr + jnp.where(here, mass, 0.0)
 
-        m_prev = m_ref[0]                                    # [KVH, G]
-        l_prev = l_ref[0]
-        acc_prev = acc_ref[0]                                # [KVH, G, hd]
+    def compute(k_ref, v_ref):
+        r, first = row_ref[i], lblk_ref[i]
 
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.where(m_prev <= NEG_INF / 2, 0.0,
-                         jnp.exp(m_prev - m_new))
-        l_new = l_prev * corr + p.sum(-1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=F32)                      # [KVH, G, hd]
+        def body(j, carry):
+            block(k_ref, v_ref, r, first, j)
+            return carry
 
-        acc_ref[0] = acc_prev * corr[..., None] + pv
-        m_ref[0] = m_new
-        l_ref[0] = l_new
-        heat_ref[...] = jnp.full(heat_ref.shape, p.sum(), F32)
+        jax.lax.fori_loop(0, k_ref.shape[0], body, 0)
 
-    @pl.when(jnp.logical_not(page_ok))
-    def _skip():
-        heat_ref[...] = jnp.zeros(heat_ref.shape, F32)
+    live = i < n_ref[0]
+    kind = kind_ref[i]
+    for c in range(SLICE_KINDS):
+        pl.when(live & (kind == c))(
+            functools.partial(compute, *kv_refs[2 * c:2 * c + 2]))
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _finish():
+        l = l_sc[...]
+        inv = jnp.where(l > 0, 1.0 / jnp.where(l > 0, l, 1.0), 0.0)
+        o_ref[...] = acc_sc[...] * inv
+        heat_ref[...] = jnp.sum(jnp.sum(hp_sc[...] * inv, axis=1), axis=1)
 
 
-def paged_class_partials(q, pool_k, pool_v, page_table, logical_idx, lengths,
-                         *, page_blocks: int, block_tokens: int,
-                         window: int | None = None, interpret: bool = False,
-                         active=None):
-    """One size class. q: [B,H,hd]; pools: [NB,bt,KVH,hd];
-    page_table/logical_idx: [B,MP] int32 (phys start block / logical page,
-    -1 = pad); lengths: [B] int32.
+def _padded_bytes(shape, dtype) -> int:
+    """VMEM bytes of an array whose two minor dims are tiled."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    *major, a, b = shape
+    return (math.prod(major) * (-(-a // sub) * sub) * (-(-b // 128) * 128)
+            * item)
 
-    ``active`` ([B] bool, optional) masks out whole lanes — an inactive lane
-    is exactly "every page invalid", so it folds into the existing per-page
-    ``page_ok`` gate by blanking the lane's table row before prefetch; the
-    kernel body and its scalar-prefetch arity are unchanged (no recompile
-    churn against cached executables).
 
-    Returns (acc [B,H,hd] f32, m [B,H] f32, l [B,H] f32, heat [B,MP] f32).
-    """
+def paged_decode_kernel(q, pool_k, pool_v, work, lengths, *,
+                        block_tokens: int, max_blocks: int,
+                        window: int | None = None, interpret: bool = False):
+    """q: [B,H,hd]; pools: [NB,bt,KVH,hd]; ``work``: the ``ops.PageWork``
+    lists (row, lblk, kind [W] int32; count [1]; at [SLICE_KINDS, W]);
+    lengths: [B] tokens valid, the current one included.
+
+    One grid step per work item (at least one): the step's K and V blocks
+    of 4**kind pool blocks come in through the BlockSpec of its kind, and
+    the pipeline copies the next item's while this one computes.
+
+    Returns (out [B,H,hd] f32, heat [B,max_blocks] f32): rows with nothing
+    to read get zeros."""
     B, H, hd = q.shape
     NB, bt, KVH, _ = pool_k.shape
-    MP = page_table.shape[1]
     assert bt == block_tokens
-    if active is not None:
-        page_table = jnp.where(active[:, None], page_table,
-                               jnp.asarray(-1, page_table.dtype))
-
-    kern = functools.partial(
-        _kernel, page_blocks=page_blocks, block_tokens=block_tokens,
-        kv_heads=KVH, head_dim=hd, window=window)
-
-    def pool_index(b, j, tbl, logical, lens):
-        return (jnp.maximum(tbl[b, j], 0) // page_blocks, 0, 0, 0)
-
-    # Every block's two minor dims equal the array's (the TPU lowering's
-    # rule for blocks not tiled by (8, 128)): the flash state is laid out
-    # [B, KVH, G(, hd)] so that one lane's block is the whole minor slab,
-    # and heat is [B, MP, 1, 1] so that each page owns a (1, 1) block.
     G = H // KVH
-    state = lambda b, j, *refs: (b, 0, 0, 0)                   # noqa: E731
+    MBp = -(-max_blocks // 128) * 128
+    kern = functools.partial(_kernel, block_tokens=bt, window=window)
+    state = (B, G, KVH)
+    whole = lambda i, *refs: (0,) * 4                      # noqa: E731
+    kv_specs, vmem = [], 0
+    for c in range(SLICE_KINDS):
+        blk = (4 ** c, bt, KVH, hd)
+        at = lambda i, *refs, c=c: (refs[5 + c][i], 0, 0, 0)   # noqa: E731
+        kv_specs += [pl.BlockSpec(blk, at), pl.BlockSpec(blk, at)]
+        vmem += 4 * _padded_bytes(blk, pool_k.dtype)      # K, V; two buffers
+    # a block's K and V in f32 and their products with q and p
+    vmem += (4 * _padded_bytes((bt, KVH, hd), F32)
+             + 2 * _padded_bytes((B, G, KVH, hd), q.dtype)
+             + 4 * _padded_bytes(state + (hd,), F32)
+             + 2 * _padded_bytes(state + (MBp,), F32)
+             + 8 * _padded_bytes((bt, KVH, 1), F32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, MP),
-        in_specs=[
-            pl.BlockSpec((1, KVH, G, hd), state),
-            pl.BlockSpec((page_blocks, bt, KVH, hd), pool_index),
-            pl.BlockSpec((page_blocks, bt, KVH, hd), pool_index),
-        ],
+        num_scalar_prefetch=5 + SLICE_KINDS,
+        grid=(jnp.maximum(work.count[0], 1),),
+        in_specs=[pl.BlockSpec((B, G, KVH, hd), whole)] + kv_specs,
         out_specs=[
-            pl.BlockSpec((1, KVH, G, hd), state),
-            pl.BlockSpec((1, KVH, G), lambda b, j, *refs: (b, 0, 0)),
-            pl.BlockSpec((1, KVH, G), lambda b, j, *refs: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda b, j, *refs: (b, j, 0, 0)),
+            pl.BlockSpec((B, G, KVH, hd), whole),
+            pl.BlockSpec((B, MBp), lambda i, *refs: (0, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM(state + (1,), F32),
+                        pltpu.VMEM(state + (1,), F32),
+                        pltpu.VMEM(state + (hd,), F32),
+                        pltpu.VMEM(state + (MBp,), F32)],
     )
-    out_shapes = [
-        jax.ShapeDtypeStruct((B, KVH, G, hd), F32),
-        jax.ShapeDtypeStruct((B, KVH, G), F32),
-        jax.ShapeDtypeStruct((B, KVH, G), F32),
-        jax.ShapeDtypeStruct((B, MP, 1, 1), F32),
-    ]
-    acc, m, l, heat = pl.pallas_call(
+    out, heat = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=out_shapes,
+        out_shape=[jax.ShapeDtypeStruct((B, G, KVH, hd), F32),
+                   jax.ShapeDtypeStruct((B, MBp), F32)],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(page_table, logical_idx, lengths, q.reshape(B, KVH, G, hd), pool_k,
-      pool_v)
-    return (acc.reshape(B, H, hd), m.reshape(B, H), l.reshape(B, H),
-            heat.reshape(B, MP))
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem + (8 << 20)),
+    )(work.row, work.lblk, work.kind, work.count, lengths,
+      *work.at,
+      q.reshape(B, KVH, G, hd).transpose(0, 2, 1, 3),
+      *([pool_k, pool_v] * SLICE_KINDS))
+    out = out.transpose(0, 2, 1, 3)
+    return out.reshape(B, H, hd), heat[:, :max_blocks]

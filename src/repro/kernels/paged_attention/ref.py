@@ -1,144 +1,38 @@
-"""Pure-jnp oracle for multi-size paged flash-decoding.
+"""Host oracle for the page-class derivation in ``ops.page_classes``.
 
-Semantics: for one page-size class c (page = page_blocks consecutive pool
-blocks, buddy-aligned), given each sequence's class-c page list, compute the
-UNNORMALIZED flash partials over exactly those pages:
-
-    m[b,h]   = max score over the class's valid tokens (NEG_INF if none)
-    l[b,h]   = sum exp(score - m)
-    acc[b,h] = sum exp(score - m) * v
-
-plus per-page attention *mass* (sum of exp(score - m_global_proxy)) — the
-heat signal.  Heat uses the class-local max (it is combined after global
-renormalization in ops.combine, so relative mass within a step is what
-matters for DAMON).
+The attention itself has its reference in the gather path
+(``repro.models.decode.paged_decode_attention_gather``), which the kernel's
+tests compare against.
 """
 
 from __future__ import annotations
 
-import math
+import numpy as np
 
-import jax
-import jax.numpy as jnp
-
-F32 = jnp.float32
-NEG_INF = -1e30
+from ...core.context import NUM_ORDERS
 
 
-def paged_class_partials_ref(q, pool_k, pool_v, page_table, logical_idx,
-                             lengths, *, page_blocks: int, block_tokens: int,
-                             window: int | None = None, active=None):
-    """q: [B,H,hd]; pools: [NB,bt,KVH,hd];
-    page_table: [B,MP] int32 physical START BLOCK of each class page (-1 pad),
-    buddy-aligned to page_blocks; logical_idx: [B,MP] int32 logical page index
-    (position = logical_idx * page_blocks * bt + offset); lengths: [B] tokens
-    valid (including current); active: optional [B] bool lane mask (an
-    inactive lane behaves as all pages invalid — mirrors the kernel).
-
-    Returns (acc [B,H,hd] f32, m [B,H] f32, l [B,H] f32, heat [B,MP] f32).
-    """
-    if active is not None:
-        page_table = jnp.where(active[:, None], page_table,
-                               jnp.asarray(-1, page_table.dtype))
-    B, H, hd = q.shape
-    NB, bt, KVH, _ = pool_k.shape
-    MP = page_table.shape[1]
-    G = H // KVH
-    pt = block_tokens * page_blocks           # tokens per class page
-    scale = 1.0 / math.sqrt(hd)
-
-    # gather pages: each page = page_blocks consecutive pool rows
-    start = jnp.maximum(page_table, 0)                         # [B,MP]
-    offs = jnp.arange(page_blocks)[None, None, :]              # [1,1,pb]
-    rows = (start[..., None] + offs).reshape(B, MP * page_blocks)
-    k = pool_k[rows].reshape(B, MP, pt, KVH, hd)
-    v = pool_v[rows].reshape(B, MP, pt, KVH, hd)
-
-    qg = q.reshape(B, KVH, G, hd).astype(F32)
-    s = jnp.einsum("bkgd,bptkd->bkgpt", qg, k.astype(F32)) * scale
-
-    pos = (jnp.maximum(logical_idx, 0)[:, :, None] * pt
-           + jnp.arange(pt)[None, None, :])                    # [B,MP,pt]
-    valid = (page_table >= 0)[:, :, None] & (pos < lengths[:, None, None])
-    if window is not None:
-        valid &= pos > (lengths[:, None, None] - 1 - window)
-    s = jnp.where(valid[:, None, None], s, NEG_INF)
-
-    s_flat = s.reshape(B, KVH, G, MP * pt)
-    m = jnp.max(s_flat, axis=-1)                               # [B,KVH,G]
-    p = jnp.exp(s_flat - m[..., None])
-    p = jnp.where(valid.reshape(B, 1, 1, MP * pt), p, 0.0)
-    l = p.sum(-1)
-    acc = jnp.einsum("bkgs,bskd->bkgd", p,
-                     v.reshape(B, MP * pt, KVH, hd).astype(F32))
-    heat = p.sum(axis=(1, 2)).reshape(B, MP, pt).sum(-1)       # [B,MP]
-    return (acc.reshape(B, H, hd), m.reshape(B, H), l.reshape(B, H), heat)
-
-
-def combine_partials_ref(parts):
-    """Combine flash partials [(acc,m,l), ...] -> normalized out [B,H,hd]."""
-    m_g = parts[0][1]
-    for _, m, _ in parts[1:]:
-        m_g = jnp.maximum(m_g, m)
-    l_g = jnp.zeros_like(m_g)
-    acc_g = jnp.zeros_like(parts[0][0])
-    for acc, m, l in parts:
-        corr = jnp.exp(m - m_g)
-        # fully-masked partials (m == NEG_INF) contribute nothing
-        corr = jnp.where(m <= NEG_INF / 2, 0.0, corr)
-        l_g = l_g + l * corr
-        acc_g = acc_g + acc * corr[..., None]
-    return acc_g / jnp.maximum(l_g, 1e-30)[..., None]
-
-
-def paged_class_heat_running_ref(q, pool_k, pool_v, page_table, logical_idx,
-                                 lengths, *, page_blocks: int,
-                                 block_tokens: int, window: int | None = None):
-    """Oracle for the KERNEL's heat semantics: pages visited sequentially,
-    each page's mass normalized against the running max at visit time."""
-    B, H, hd = q.shape
-    NB, bt, KVH, _ = pool_k.shape
-    MP = page_table.shape[1]
-    G = H // KVH
-    pt = block_tokens * page_blocks
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KVH, G, hd).astype(F32)
-
-    heat = jnp.zeros((B, MP), F32)
-    m_run = jnp.full((B, KVH, G), NEG_INF, F32)
-    for j in range(MP):
-        start = jnp.maximum(page_table[:, j], 0)
-        rows = start[:, None] + jnp.arange(page_blocks)[None, :]
-        k = pool_k[rows].reshape(B, pt, KVH, hd)
-        s = jnp.einsum("bkgd,btkd->bkgt", qg, k.astype(F32)) * scale
-        pos = (jnp.maximum(logical_idx[:, j], 0)[:, None] * pt
-               + jnp.arange(pt)[None, :])
-        valid = (page_table[:, j] >= 0)[:, None] & (pos < lengths[:, None])
-        if window is not None:
-            valid &= pos > (lengths[:, None] - 1 - window)
-        s = jnp.where(valid[:, None, None], s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.where((page_table[:, j] >= 0)[:, None, None],
-                          jnp.maximum(m_run, m_cur), m_run)
-        p = jnp.where(valid[:, None, None],
-                      jnp.exp(s - m_new[..., None]), 0.0)
-        hj = jnp.where(page_table[:, j] >= 0, p.sum(axis=(1, 2, 3)), 0.0)
-        heat = heat.at[:, j].set(hj)
-        m_run = m_new
-    return heat
-
-
-def paged_decode_ref(q, pool_k, pool_v, page_tables, logical_idxs, lengths, *,
-                     block_tokens: int, window=None):
-    """Full multi-class oracle: page_tables/logical_idxs are dicts
-    {order: [B, MP_c]}; page_blocks = 4**order."""
-    parts = []
-    heats = {}
-    for order, tbl in sorted(page_tables.items()):
-        acc, m, l, heat = paged_class_partials_ref(
-            q, pool_k, pool_v, tbl, logical_idxs[order], lengths,
-            page_blocks=4 ** order, block_tokens=block_tokens, window=window)
-        parts.append((acc, m, l))
-        heats[order] = heat
-    out = combine_partials_ref(parts)
-    return out.astype(q.dtype), heats
+def page_classes_ref(block_table, first, end) -> np.ndarray:
+    """Order of the largest page that holds each live block (-1 where the
+    block is not read), by a walk over each row: a page of order c is an
+    aligned run of 4^c live logical blocks whose physical blocks are
+    consecutive and start on a multiple of 4^c."""
+    tbl = np.asarray(block_table)
+    B, MB = tbl.shape
+    out = np.full((B, MB), -1, np.int64)
+    for b in range(B):
+        live = [(j >= first[b]) and (j < end[b]) and tbl[b, j] >= 0
+                for j in range(MB)]
+        for j in range(MB):
+            if not live[j]:
+                continue
+            out[b, j] = 0
+            for c in range(1, NUM_ORDERS):
+                n = 4 ** c
+                s = j - j % n
+                run = range(s, s + n)
+                if (s + n <= MB and all(live[i] for i in run)
+                        and tbl[b, s] % n == 0
+                        and all(tbl[b, i] == tbl[b, s] + i - s for i in run)):
+                    out[b, j] = c
+    return out

@@ -6,14 +6,19 @@ layer reads KV through the block table (the page-table analogue) and emits
 per-block attention mass — the DAMON heat signal that drives promotion
 decisions.
 
-Two attention backends:
-  * "gather"      — reference/jnp path: gather blocks then dense attention.
-                    Used by the CPU engine and as the dry-run BASELINE (its
-                    lowering shows the collective cost of naive paged reads
-                    on a sharded pool — see EXPERIMENTS.md §Perf).
+Three attention backends:
+  * "gather"      — reference/jnp path: gather every table block then dense
+                    attention.  Used by the CPU engine, by configs the kernel
+                    does not cover, and as the dry-run BASELINE (its lowering
+                    shows the collective cost of naive paged reads on a
+                    sharded pool — see EXPERIMENTS.md §Perf).
+  * "paged"       — the Pallas kernel (kernels/paged_attention): reads only
+                    the live pages, one contiguous DMA per buddy-aligned page
+                    (a 64-block page in four 16-block slices), K/V read in
+                    the pool's dtype.
+                    The engine's decode step on a TPU.
   * "flashdecode" — shard_map flash-decoding over the ("data","model")-sharded
-                    pool with shard-local block lists; the optimized path
-                    (and the structure the Pallas kernel plugs into).
+                    pool with shard-local block lists.
 
 Cache layout (pytree mirroring the block segmentation of transformer.py):
   attn (GQA) : {"pool_k","pool_v"}: [NB, bt, KVH, hd]  (stacked [reps,...] in scans)
@@ -33,6 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..core.context import NUM_ORDERS
+from ..kernels.paged_attention.ops import page_work, paged_decode_attention
 from .attention import flash_attention
 from .common import BF16, F32, apply_mrope, apply_rope, pad_vocab, rms_norm, \
     sinusoidal_position_at, sinusoidal_positions
@@ -175,6 +182,52 @@ def paged_decode_attention_mla_gather(q_eff, q_rope, pool_ckv, block_table,
     return o_lat, heat
 
 
+def paged_kernel_supported(cfg: ModelConfig) -> bool:
+    """Whether every attention layer of ``cfg`` is one the paged kernel
+    computes: plain GQA/MHA, windowed or not, with no logit soft cap (and
+    the config has an attention layer)."""
+    return (cfg.mla is None and cfg.attn.logit_soft_cap is None
+            and "a" in cfg.layer_kinds())
+
+
+def _attn_windows(cfg: ModelConfig) -> dict:
+    """window (None = global) -> number of decoder layers attending with it."""
+    out: dict = {}
+    for plan in build_layer_plans(cfg, decoder=True):
+        if plan.kind != "mamba":
+            w = cfg.attn.window if plan.local else None
+            out[w] = out.get(w, 0) + 1
+    return out
+
+
+def _mask_inactive(block_table, lengths, active):
+    """Inactive rows see an all ``-1`` table and length 0."""
+    if active is None:
+        return block_table, lengths
+    return (jnp.where(active[:, None], block_table,
+                      jnp.asarray(-1, block_table.dtype)),
+            jnp.where(active, lengths, jnp.asarray(0, lengths.dtype)))
+
+
+def decode_page_work(cfg: ModelConfig, block_table, lengths,
+                     layout: PagedLayout, active=None) -> dict:
+    """The paged kernel's work lists for one decode step, per attention
+    window (``{window: PageWork}``); arguments as ``decode_step``'s."""
+    block_table, lengths = _mask_inactive(block_table, lengths, active)
+    return {w: page_work(block_table, lengths + 1,
+                         block_tokens=layout.block_tokens, window=w)
+            for w in _attn_windows(cfg)}
+
+
+def page_reads(cfg: ModelConfig, works: dict) -> jax.Array:
+    """[NUM_ORDERS] int32: K/V blocks the step's kernel calls read, by the
+    order of the page that holds them, over every attention layer."""
+    total = jnp.zeros((NUM_ORDERS,), jnp.int32)
+    for w, n in _attn_windows(cfg).items():
+        total = total + n * works[w].reads
+    return total
+
+
 # ---------------------------------------------------------------------------
 # KV pool writes
 # ---------------------------------------------------------------------------
@@ -216,7 +269,8 @@ def _decode_attn_layer(cfg: ModelConfig, plan: LayerPlan, p: Pytree,
                        cache: Pytree, x: jax.Array, lengths: jax.Array,
                        block_table: jax.Array, layout: PagedLayout,
                        pos3d=None, attn_impl: str = "gather",
-                       sharded_table=None, sharded_logical=None):
+                       sharded_table=None, sharded_logical=None,
+                       works=None):
     """x: [B,d] -> (out [B,d], new cache, heat [B,MB])."""
     B, d = x.shape
     H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
@@ -280,6 +334,10 @@ def _decode_attn_layer(cfg: ModelConfig, plan: LayerPlan, p: Pytree,
             q, pool_k, pool_v, sharded_table, sharded_logical, lengths + 1,
             block_tokens=bt, window=window, soft_cap=cfg.attn.logit_soft_cap,
             batch_sharded=not attn_impl.endswith("blocksharded"))
+    elif attn_impl == "paged":
+        out, heat = paged_decode_attention(
+            q, pool_k, pool_v, block_table, lengths + 1, block_tokens=bt,
+            window=window, work=works[window])
     else:
         out, heat = paged_decode_attention_gather(
             q, pool_k, pool_v, block_table, lengths + 1,
@@ -291,7 +349,7 @@ def _decode_attn_layer(cfg: ModelConfig, plan: LayerPlan, p: Pytree,
 
 def _decode_layer(cfg, plan, p, cache, x, lengths, block_table, layout,
                   pos3d=None, attn_impl="gather", sharded_table=None,
-                  sharded_logical=None):
+                  sharded_logical=None, works=None):
     h = _apply_norm(cfg, p["ln1"], x)
     heat = jnp.zeros((x.shape[0], layout.max_blocks), F32)
     new_cache = dict(cache)
@@ -302,7 +360,7 @@ def _decode_layer(cfg, plan, p, cache, x, lengths, block_table, layout,
     else:
         y, st, heat = _decode_attn_layer(cfg, plan, p["attn"], cache, h, lengths,
                                          block_table, layout, pos3d, attn_impl,
-                                         sharded_table, sharded_logical)
+                                         sharded_table, sharded_logical, works)
         x = x + y
         new_cache.update(st)
     if plan.xattn:
@@ -330,7 +388,7 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
                 active: jax.Array | None = None,
                 pos3d: jax.Array | None = None, compute_dtype=BF16,
                 attn_impl: str = "gather", sharded_table=None,
-                sharded_logical=None):
+                sharded_logical=None, works: dict | None = None):
     """One decode step for the batch.
 
     tokens: [B] int32 (the tokens at position ``lengths``); lengths: [B]
@@ -344,13 +402,14 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
     length-0 decode would scatter garbage KV into its first block (the PR 1
     scatter-to-block-0 bug class).  ``active=None`` keeps the historical
     caller-builds-a-fresh-table behavior.
+    ``attn_impl="paged"`` runs the Pallas kernel over ``works``
+    (``decode_page_work`` of the same arguments, derived here when None).
     Returns (logits [B, V_pad], new_cache, heat [B, MB]).
     """
     B = tokens.shape[0]
-    if active is not None:
-        block_table = jnp.where(active[:, None], block_table,
-                                jnp.asarray(-1, block_table.dtype))
-        lengths = jnp.where(active, lengths, jnp.asarray(0, lengths.dtype))
+    if attn_impl == "paged" and works is None:
+        works = decode_page_work(cfg, block_table, lengths, layout, active)
+    block_table, lengths = _mask_inactive(block_table, lengths, active)
     x = params["embed"].astype(compute_dtype)[tokens]
     segs = build_segments(build_layer_plans(cfg, decoder=True))
     if cfg.enc_dec:
@@ -363,7 +422,7 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
             x, c, h = _decode_layer(cfg, seg[1], params["blocks"][key],
                                     cache[key], x, lengths, block_table,
                                     layout, pos3d, attn_impl,
-                                    sharded_table, sharded_logical)
+                                    sharded_table, sharded_logical, works)
             new_cache[key] = c
             heat_total = heat_total + h
         else:
@@ -377,7 +436,7 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
                     xx, cj, hj = _decode_layer(
                         cfg, pl, layer_params[f"m{j}"], layer_cache[f"m{j}"],
                         xx, lengths, block_table, layout, pos3d, attn_impl,
-                        sharded_table, sharded_logical)
+                        sharded_table, sharded_logical, works)
                     new_lc[f"m{j}"] = cj
                     hh = hh + hj
                 return (xx, hh), new_lc

@@ -53,9 +53,10 @@ from ..obs.ringbuf import EV_PREEMPT
 from ..obs.spans import span, spanned
 
 from ..configs.base import ModelConfig
-from ..core import (MAX_PROFILE_REGIONS, FaultKind, HWSpec, Khugepaged,
-                    KhugepagedConfig, MemoryManager, MMOutOfMemory, Profile,
-                    ProfileSynthesizer, TieredMemoryManager,
+from ..core import (MAX_PROFILE_REGIONS, NUM_ORDERS, FaultKind, HWSpec,
+                    Khugepaged, KhugepagedConfig, MemoryManager,
+                    MMOutOfMemory, Profile, ProfileSynthesizer,
+                    TieredMemoryManager,
                     default_tier_chain, ebpf_mm_program, make_cost_model,
                     never_program, profile_wss_program, reclaim_lru_program,
                     thp_always_program, tier_damon_program,
@@ -66,7 +67,8 @@ from ..core.hooks import HOOK_EVICT, HOOK_FAULT, HOOK_PROFILE, HOOK_TIER
 from ..core.programs import (evict_ghost_program, evict_lfu_program,
                              evict_lru_program)
 from ..resilience import FailureInjector
-from ..models.decode import (PagedLayout, cache_init, decode_step,
+from ..models.decode import (PagedLayout, cache_init, decode_page_work,
+                             decode_step, page_reads, paged_kernel_supported,
                              prefill_step, prefill_suffix_step)
 from ..models.transformer import build_layer_plans
 from .prefix_cache import PrefixCache
@@ -78,6 +80,16 @@ Pytree = Any
 
 # finished requests whose times the engine keeps (the newest)
 REQUEST_LOG = 4096
+
+
+def decode_attn_impl(cfg: ModelConfig, cache: Pytree) -> str:
+    """Decode attention backend: the Pallas paged kernel where the pool
+    lives on a TPU and every attention layer is one it computes, else the
+    gather path (the CPU engine, MLA, soft-capped logits)."""
+    dev = next(iter(jax.tree.leaves(cache)[0].devices()))
+    if dev.platform == "tpu" and paged_kernel_supported(cfg):
+        return "paged"
+    return "gather"
 
 
 @dataclass
@@ -125,15 +137,24 @@ class EngineStats:
     # persistent cache), in all and per entry
     compiles: int = 0
     compiles_by_entry: dict = field(default_factory=dict)
+    # K/V blocks the paged decode kernel read, over every attention layer,
+    # by the order of the page that held them (the kernel's own count)
+    kv_blocks_read_by_order: list = field(
+        default_factory=lambda: [0] * NUM_ORDERS)
 
     def count_compile(self, entry: str) -> None:
         self.compiles += 1
         self.compiles_by_entry[entry] = \
             self.compiles_by_entry.get(entry, 0) + 1
 
+    def count_reads(self, reads) -> None:
+        self.kv_blocks_read_by_order = [
+            a + int(b) for a, b in zip(self.kv_blocks_read_by_order, reads)]
+
     def snapshot(self) -> dict:
         out = dict(self.__dict__)
         out["compiles_by_entry"] = dict(self.compiles_by_entry)
+        out["kv_blocks_read_by_order"] = list(self.kv_blocks_read_by_order)
         return out
 
 
@@ -383,12 +404,20 @@ class ServingEngine:
             trow = jnp.where(tri[:, 0] >= 0, tri[:, 0], buf.shape[0])
             return buf.at[trow, tri[:, 1]].set(tri[:, 2], mode="drop")
 
+        self.attn_impl = impl = decode_attn_impl(cfg, self.cache)
+
         def _decode_entry(p, c, buf, didx, drows, tri, t, l, act, pos3d):
             buf = _install_rows(buf, didx, drows, tri)
+            works = (decode_page_work(cfg, buf, l, layout, act)
+                     if impl == "paged" else None)
             logits, new_cache, heat = decode_step(
                 p, cfg, c, t, l, buf, layout, active=act, pos3d=pos3d,
-                attn_impl="gather")
-            return logits, new_cache, heat, buf
+                attn_impl=impl, works=works)
+            reads = (page_reads(cfg, works) if works is not None
+                     else jnp.zeros((NUM_ORDERS,), jnp.int32))
+            # what the step's attention saw: per-block heat and the blocks
+            # its kernel read by page order
+            return logits, new_cache, (heat, reads), buf
 
         def _prefill_entry(p, c, buf, didx, drows, tri, t, slot, last, **kw):
             buf = _install_rows(buf, didx, drows, tri)
@@ -892,13 +921,14 @@ class ServingEngine:
         if self.cfg.vlm_patches:
             pos3d = jnp.asarray(
                 np.tile(lengths.astype(np.float32)[None, :, None], (3, 1, 1)))
-        logits, self.cache, heat, self._table_buf = self._decode(
+        logits, self.cache, (heat, reads), self._table_buf = self._decode(
             self.params, self.cache, self._table_buf, didx, drows, tri,
             jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(active), pos3d)
         with span("serve/sync"):
-            logits_np = np.asarray(logits)
-            heat_np = np.asarray(heat)
+            logits_np, heat_np, reads_np = jax.device_get(
+                (logits, heat, reads))
+        self.stats.count_reads(reads_np)
         self._fold_and_sample(skipped, logits_np, heat_np)
 
     @spanned("serve/sample")

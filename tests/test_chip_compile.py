@@ -22,7 +22,7 @@ from repro.core import (HWSpec, MemoryManager, PredicatedPolicy,
 from repro.core.context import CTX_LEN
 from repro.core.isa import NUM_REGS
 from repro.kernels.block_copy.ops import apply_moves
-from repro.kernels.paged_attention.kernel import paged_class_partials
+from repro.kernels.paged_attention.ops import paged_decode_attention
 from repro.launch.serve import default_profile
 from repro.models import PagedLayout, cache_spec, model_spec
 from repro.models.common import BF16, ParamSpec
@@ -77,17 +77,21 @@ def _device_bytes(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("order", [0, 1, 2])
-def test_paged_attention_kernel(one_chip, order):
-    KVH, hd, H = CFG.kv_heads, CFG.head_dim, CFG.n_heads
-    pb = 4 ** order
-    MP = MB // pb
-    fn = jax.jit(lambda q, k, v, t, lg, n: paged_class_partials(
-        q, k, v, t, lg, n, page_blocks=pb, block_tokens=BT))
-    pool = _shape(one_chip, (NB, BT, KVH, hd), BF16)
-    table = _shape(one_chip, (B, MP), jnp.int32)
-    compiled = fn.lower(_shape(one_chip, (B, H, hd), BF16), pool, pool,
-                        table, table,
+@pytest.mark.parametrize("kv_heads,head_dim,pool", [
+    (32, 96, NB),         # Phi-3-mini: hd 96, padded to 128 lanes
+    (32, 128, 768),       # DeepSeek-LLM-7B
+    (8, 128, NB),         # GQA, 4 query heads per KV head
+], ids=["phi3", "deepseek", "gqa"])
+def test_paged_attention_kernel(one_chip, kv_heads, head_dim, pool):
+    """The paged decode kernel and its work-list derivation, as the decode
+    step runs them: one program for every page order (a 64-block page
+    streams as 16-block slices, so the largest VMEM block is 16 blocks)."""
+    H = CFG.n_heads
+    fn = jax.jit(lambda q, k, v, t, n: paged_decode_attention(
+        q, k, v, t, n, block_tokens=BT))
+    kv = _shape(one_chip, (pool, BT, kv_heads, head_dim), BF16)
+    compiled = fn.lower(_shape(one_chip, (B, H, head_dim), BF16), kv, kv,
+                        _shape(one_chip, (B, MB), jnp.int32),
                         _shape(one_chip, (B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -134,14 +138,17 @@ def _tree_bytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
-@pytest.mark.parametrize("n_layers", [2, 32])
-def test_decode_step_fits_one_chip(one_chip, n_layers):
-    """The engine's decode step (gather attention, active mask) compiles,
-    takes weights + pool as arguments, and with its un-donated output pool
-    and temporaries fits the chip at chip_smoke's pool size."""
+@pytest.mark.parametrize("n_layers,attn_impl", [
+    (2, "gather"), (32, "gather"), (2, "paged"), (32, "paged")],
+    ids=["2", "32", "paged-2", "paged-32"])
+def test_decode_step_fits_one_chip(one_chip, n_layers, attn_impl):
+    """The engine's decode step (gather attention or the paged kernel,
+    active mask) compiles, takes weights + pool as arguments, and with its
+    un-donated output pool and temporaries fits the chip at chip_smoke's
+    pool size."""
     cfg, layout, params, cache = _step_args(one_chip, n_layers)
     fn = jax.jit(lambda p, c, t, n, tb, a: decode_step(
-        p, cfg, c, t, n, tb, layout, active=a, attn_impl="gather"))
+        p, cfg, c, t, n, tb, layout, active=a, attn_impl=attn_impl))
     vec = _shape(one_chip, (B,), jnp.int32)
     compiled = fn.lower(params, cache, vec, vec,
                         _shape(one_chip, (B, MB), jnp.int32),
