@@ -1,20 +1,155 @@
 """A cell's files, the device check, and the engine a configuration
-describes.  Shared by ``run.py`` and the calibration scripts."""
+describes.  Shared by ``run.py`` and the calibration scripts.
+
+A configuration file (``configs/<name>.json``) states the model in the
+keys of its published ``config.json`` and says what runs: ``arch`` names
+the program's registry entry, and ``program`` overrides fields of that
+``ModelConfig`` by dotted path (``{"n_layers": 15}``, ``{"moe.top_k": 6}``),
+the cut to one chip's share.  ``served_config`` applies the overrides and
+then holds every published key that ``HF_KEYS`` knows to the config that
+is served: a key the file names under ``assumed`` is a departure stated
+there and is not held.  A key under ``reduced`` differs from its
+``published`` value.  At smoke size the registry's smoke model runs as it
+is, and ``ref_sizes`` gives the reference that model's sizes under the
+same keys.
+"""
 
 from __future__ import annotations
 
 import json
 import os
 import sys
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, fields, replace
 from pathlib import Path
+
+import workcount
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 
+# published config.json key -> where the program's ModelConfig holds it: a
+# dotted field path, or a function of the config.  Families that name one
+# size differently map their names to the same field.
+HF_KEYS = {
+    "hidden_size": "d_model",
+    "intermediate_size": "d_ff",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "kv_heads",
+    "head_dim": "head_dim",
+    "num_hidden_layers": "n_layers",
+    "vocab_size": "vocab",
+    "tie_word_embeddings": "tie_embeddings",
+    "rope_theta": "attn.rope_theta",
+    "sliding_window": "attn.window",
+    "attn_logit_softcapping": "attn.logit_soft_cap",
+    # sparse experts; a file states the routed experts held here, the
+    # chip's share (the router keeps the published count)
+    "n_routed_experts": lambda c: c.moe and workcount.experts_held(c),
+    "num_experts": lambda c: c.moe and workcount.experts_held(c),
+    "num_local_experts": lambda c: c.moe and workcount.experts_held(c),
+    "num_experts_per_tok": "moe.top_k",
+    "moe_intermediate_size": "moe.d_ff_expert",
+    "n_shared_experts": "moe.num_shared",
+    "first_k_dense_replace": "moe.first_dense",
+    "moe_layer_freq": "moe.every",
+    "expert_layer_offset": "moe.first_dense",
+    "expert_layer_period": "moe.every",
+    # latent attention
+    "kv_lora_rank": "mla.kv_lora",
+    "qk_nope_head_dim": "mla.qk_nope",
+    "qk_rope_head_dim": "mla.qk_rope",
+    "v_head_dim": "mla.v_head",
+    # state-space layers and their interleave with attention
+    "mamba_d_state": "mamba.d_state",
+    "ssm_state_size": "mamba.d_state",
+    "mamba_d_conv": "mamba.conv_dim",
+    "conv_kernel": "mamba.conv_dim",
+    "mamba_expand": "mamba.expand",
+    "expand": "mamba.expand",
+    "mamba_head_dim": "mamba.head_dim",
+    "mamba_chunk_size": "mamba.chunk",
+    "chunk_size": "mamba.chunk",
+    "attn_layer_period": lambda c: (len(c.hybrid_pattern)
+                                    if c.hybrid_pattern else None),
+    "attn_layer_offset": lambda c: (c.hybrid_pattern.index("a")
+                                    if c.hybrid_pattern else None),
+}
+
 
 class CellError(RuntimeError):
     pass
+
+
+def _program_on_path() -> None:
+    if str(ROOT / "src") not in sys.path:
+        sys.path.insert(0, str(ROOT / "src"))
+
+
+def hf_value(cfg, key: str):
+    """The value of published key ``key`` in a ModelConfig (None where the
+    config has no such part)."""
+    where = HF_KEYS[key]
+    if callable(where):
+        return where(cfg)
+    for name in where.split("."):
+        if cfg is None:
+            return None
+        cfg = getattr(cfg, name)
+    return cfg
+
+
+def _override(obj, path: str, value):
+    """``obj`` with the field at dotted ``path`` set to ``value``."""
+    head, _, rest = path.partition(".")
+    if head not in {f.name for f in fields(obj)}:
+        raise CellError(f"program override {path!r}: "
+                        f"{type(obj).__name__} has no field {head!r}")
+    old = getattr(obj, head)
+    if rest:
+        if old is None:
+            raise CellError(f"program override {path!r}: the config has no "
+                            f"{head}")
+        value = _override(old, rest, value)
+    elif isinstance(old, tuple) and isinstance(value, list):
+        value = tuple(value)
+    return replace(obj, **{head: value})
+
+
+def served_config(cfile: dict):
+    """The ModelConfig a configuration file serves at full size: the
+    registry's ``arch`` with the file's ``program`` overrides, checked
+    against every published key of the file that ``HF_KEYS`` knows."""
+    _program_on_path()
+    from repro.configs.base import get_config
+    cfg = get_config(cfile["arch"])
+    for path, value in cfile.get("program", {}).items():
+        cfg = _override(cfg, path, value)
+    published = cfile.get("published", {})
+    for key in cfile.get("reduced", []):
+        if key not in published or published[key] == cfile.get(key):
+            raise CellError(f"reduced key {key!r} does not differ from its "
+                            f"published value")
+    assumed = cfile.get("assumed", {})
+    wrong = {k: (cfile[k], hf_value(cfg, k)) for k in HF_KEYS
+             if k in cfile and k not in assumed
+             and hf_value(cfg, k) != cfile[k]}
+    if wrong:
+        raise CellError(f"program config {cfile['arch']} serves another "
+                        f"model than the file states (key: file, served): "
+                        f"{wrong}")
+    return cfg
+
+
+def ref_sizes(cfg, cfile: dict) -> dict:
+    """What the reference reads for config ``cfg``: the file's keys that
+    are not sizes (``rms_norm_eps``), and every ``HF_KEYS`` size of
+    ``cfg``."""
+    out = {k: v for k, v in cfile.items() if k not in HF_KEYS}
+    for key in HF_KEYS:
+        v = hf_value(cfg, key)
+        if v is not None:
+            out[key] = v
+    return out
 
 
 @dataclass
@@ -59,8 +194,7 @@ def open_cell(name: str, smoke: bool = False) -> Cell:
     # environment names one
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           str(ROOT / ".cache" / "xla"))
-    if str(ROOT / "src") not in sys.path:
-        sys.path.insert(0, str(ROOT / "src"))
+    _program_on_path()
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -80,28 +214,13 @@ def open_cell(name: str, smoke: bool = False) -> Cell:
         raise CellError(f"no peaks for device kind "
                         f"{c.device.device_kind!r} in peaks.json")
 
-    from repro.configs.base import get_config, get_smoke_config
     if smoke:
-        cfg = get_smoke_config(cfile["arch"])
-        c.ref_cfg = {"num_attention_heads": cfg.n_heads,
-                     "num_key_value_heads": cfg.kv_heads,
-                     "num_hidden_layers": cfg.n_layers,
-                     "rms_norm_eps": cfile["rms_norm_eps"],
-                     "rope_theta": cfg.attn.rope_theta,
-                     "vocab_size": cfg.vocab}
+        from repro.configs.base import get_smoke_config
+        c.cfg = get_smoke_config(cfile["arch"])
+        c.ref_cfg = ref_sizes(c.cfg, cfile)
     else:
-        cfg = replace(get_config(cfile["arch"]),
-                      n_layers=cfile["num_hidden_layers"])
+        c.cfg = served_config(cfile)
         c.ref_cfg = cfile
-        want = (cfile["hidden_size"], cfile["intermediate_size"],
-                cfile["num_attention_heads"], cfile["num_key_value_heads"],
-                cfile["vocab_size"], cfile["rope_theta"])
-        got = (cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.kv_heads, cfg.vocab,
-               cfg.attn.rope_theta)
-        if want != got:
-            raise CellError(f"program config {cfile['arch']} {got} is not "
-                            f"the file's {want}")
-    c.cfg = cfg
     return c
 
 

@@ -15,7 +15,10 @@ def read(run):
     st = [s for s in traced_steps(run) if s.decode_tokens > 0]
     if not n or not st:
         return None
-    need = sum(workcount.decode_bytes(run.cfg, s.kv_rows) for s in st)
+    try:
+        need = sum(workcount.decode_bytes(run.cfg, s.kv_rows) for s in st)
+    except workcount.Uncounted:     # routed experts read: no count yet
+        return None
     # scale to the calls the trace saw, should a step straddle its edges
     need *= n / len(st)
     return 100.0 * need / (secs * run.peaks["hbm_bytes_per_s"])

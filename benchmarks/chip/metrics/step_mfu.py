@@ -5,7 +5,7 @@ from readers import window_flops
 
 
 def read(run):
-    if run.peaks is None:
+    flops = window_flops(run)
+    if run.peaks is None or flops is None:
         return None
-    return 100.0 * window_flops(run) / (
-        run.seconds * run.peaks["bf16_flops_per_s"])
+    return 100.0 * flops / (run.seconds * run.peaks["bf16_flops_per_s"])

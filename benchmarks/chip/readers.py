@@ -32,13 +32,17 @@ def traced_steps(run):
     return [s for s in steps(run) if s.t0 >= t]
 
 
-def window_flops(run) -> float:
-    """Operations of every prefill and decode token of the window's steps."""
+def window_flops(run) -> float | None:
+    """Operations of every prefill and decode token of the window's steps;
+    None where the configuration's layers cannot be counted."""
     cfg = run.cfg
     total = 0.0
-    for s in steps(run):
-        total += workcount.decode_flops(cfg, s.kv_rows)
-        for L, computed in s.admitted:
-            if computed > 0:
-                total += workcount.prefill_flops(cfg, L, computed)
+    try:
+        for s in steps(run):
+            total += workcount.decode_flops(cfg, s.kv_rows)
+            for L, computed in s.admitted:
+                if computed > 0:
+                    total += workcount.prefill_flops(cfg, L, computed)
+    except workcount.Uncounted:
+        return None
     return total

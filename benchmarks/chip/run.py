@@ -204,6 +204,11 @@ def main() -> None:
         print(f"window_compile: {d}")
     print(f"window_steps: {len(harness.window_steps(win))} "
           f"idle_s={win.idle_s}")
+    print(f"preemptions: "
+          f"{win.stats1['preemptions'] - win.stats0['preemptions']} in the "
+          f"window, {win.stats1['preemptions']} in the run; "
+          f"{sum(r.preempted > 0 for r in due)} of the requests due in the "
+          f"window preempted")
 
     run = Run(cell=args.workload, seconds=args.seconds, setup_s=setup_s,
               win=win, engine=engine, cfg=cfg,
@@ -235,7 +240,9 @@ def main() -> None:
     compared, n_tok = check.compare(ref, params, c.ref_cfg, reqs, finished,
                                     sample, traffic, policy=(rows, bad))
     print(f"check_s: {time.perf_counter() - t_check}")
-    print(f"check_requests: {len(sample)} served_tokens_compared: {n_tok}")
+    print(f"check_requests: {len(sample)} preempted: "
+          f"{sum(win.records[r].preempted > 0 for r in sample)} "
+          f"served_tokens_compared: {n_tok}")
     for k, v in compared.items():
         print(f"{k}: {v['value']} limit {v['limit']}", file=sys.stderr)
     sys.stderr.flush()

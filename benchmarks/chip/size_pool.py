@@ -17,7 +17,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-from dataclasses import replace
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -41,7 +40,7 @@ def cell_sizes(cell: dict, one_chip) -> dict:
     import jax
     import jax.numpy as jnp
     import traffic as traffic_mod
-    from repro.configs.base import get_config
+    from cell import served_config
     from repro.models import PagedLayout, cache_spec, model_spec
     from repro.models.common import BF16, ParamSpec
     from repro.models.decode import decode_step, prefill_step
@@ -49,8 +48,7 @@ def cell_sizes(cell: dict, one_chip) -> dict:
     cfile = json.loads((HERE / "configs" / f"{cell['config']}.json")
                        .read_text())
     tr = traffic_mod.load(cell["traffic"])
-    cfg = replace(get_config(cfile["arch"]),
-                  n_layers=cfile["num_hidden_layers"])
+    cfg = served_config(cfile)
     eng = cfile["engine"]
     B, bt = eng["max_batch"], eng["block_tokens"]
     layout = PagedLayout(num_blocks=tr["pool_blocks"], block_tokens=bt,

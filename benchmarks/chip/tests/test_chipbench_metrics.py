@@ -1,9 +1,10 @@
 """Every metric a cell lists in BENCHMARK.json has a reader that finds
-something to read in a run of that cell (here a hand-made one), and the
-shares stay within 0-100%."""
+something to read in a run of that cell (here a hand-made one, of the
+cell's own served configuration), and the shares stay within 0-100%."""
 
 import json
 import sys
+from dataclasses import field, make_dataclass
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -12,18 +13,24 @@ import pytest
 CHIP = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(CHIP))
 
+import cell as cell_mod  # noqa: E402
 import harness  # noqa: E402
 import reduce  # noqa: E402
 from run import Run, cell_metrics, load_module  # noqa: E402
 
 BENCH = json.loads((CHIP.parents[1] / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
-PHI3 = SimpleNamespace(n_layers=32, d_model=3072, n_heads=32, kv_heads=32,
-                       head_dim=96, d_ff=8192, vocab=32064)
+CONFIG = {w["name"]: w["config"] for w in BENCH["workloads"]}
 MS = 1_000_000
 
 
-def _run(cell: str) -> Run:
+def served(cell: str):
+    """The cell's configuration as it is served at full size."""
+    return cell_mod.served_config(json.loads(
+        (CHIP / "configs" / f"{CONFIG[cell]}.json").read_text()))
+
+
+def _run(cell: str, cfg=None) -> Run:
     recs = {}
     steps = []
     for i in range(20):
@@ -54,7 +61,7 @@ def _run(cell: str) -> Run:
     engine = SimpleNamespace(
         mm=SimpleNamespace(hugepage_block_fraction=lambda: 0.25))
     return Run(cell=cell, seconds=2.0, setup_s=42.0, win=win, engine=engine,
-               cfg=PHI3, max_batch=8,
+               cfg=cfg or served(cell), max_batch=8,
                peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
                memory={"peak_bytes_in_use": 15e9, "bytes_limit": 16e9},
                trace=trace)
@@ -103,3 +110,33 @@ def test_trace_readers_count_only_the_traced_steps():
     secs, _ = run.trace.program(("_prefill_entry", "_prefill_sfx_entry"))
     assert part == pytest.approx(secs * 1e6 / late)
     assert part > whole
+
+
+def test_a_new_engine_counter_reads_without_an_edit(monkeypatch, tmp_path):
+    """A counter the program adds to ``EngineStats`` reaches a new metric's
+    reader in the hand-made run of a sparse-expert, latent-attention
+    configuration, with no edit to the harness or its tests."""
+    from repro.configs.base import get_smoke_config
+    from repro.serving import engine as engine_mod
+    Stats = make_dataclass(
+        "EngineStats", [("expert_tokens", list,
+                         field(default_factory=lambda: [0] * 4))],
+        bases=(engine_mod.EngineStats,))
+    reader = tmp_path / "expert_tokens.py"
+    reader.write_text(
+        "def read(run):\n"
+        "    a = run.win.stats0.get('expert_tokens')\n"
+        "    b = run.win.stats1.get('expert_tokens')\n"
+        "    return None if a is None else float(sum(b) - sum(a))\n")
+    read = load_module(reader, "expert_tokens").read
+    moe = get_smoke_config("deepseek_v2_lite_16b")
+    assert read(_run("phi3.chat", cfg=moe)) is None
+    monkeypatch.setattr(engine_mod, "EngineStats", Stats)
+    run = _run("phi3.chat", cfg=moe)
+    assert read(run) > 0
+    # the layer-kind work counts read the same run
+    mfu = load_module(CHIP / "metrics" / "step_mfu.py", "step_mfu").read(run)
+    assert 0 < mfu <= 100
+    roof = load_module(CHIP / "metrics" / "decode_hbm_roofline.py",
+                       "decode_hbm_roofline").read(run)
+    assert roof is None         # routed experts read: no count
